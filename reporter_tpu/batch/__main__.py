@@ -73,7 +73,7 @@ def main(argv=None) -> int:
                          "program before phase 2 starts matching, so compile "
                          "stalls land in a visible warmup pass instead of "
                          "the first micro-batches (docs/performance.md; "
-                         "pair with $REPORTER_XLA_CACHE_DIR for warm "
+                         "pair with $JAX_COMPILATION_CACHE_DIR for warm "
                          "restarts)")
     args = ap.parse_args(argv)
 

@@ -14,7 +14,9 @@ device memory and is addressed by slot index; idle beams page to
 the CPU backend's default memory IS host DRAM, so the fallback is the
 semantically-identical twin); beams squeezed out of both tiers detach
 into their handle as a plain host dict, which is exactly the
-``SessionStore`` wire form.  ``ops/viterbi.session_step_arena`` gathers a
+``SessionStore`` wire form.  A cold page never enters a program: its
+promotion moves it explicitly into device memory first (no program mixes
+memory spaces, which JAX 0.9 refuses).  ``ops/viterbi.session_step_arena`` gathers a
 step's rows by slot, decodes, and scatters the successors back with the
 slab DONATED — one in-place dispatch, zero per-step beam transfers.
 
@@ -185,60 +187,30 @@ class SessionArena:
         self.promotions = 0
         self.evictions = 0
         self.readbacks = 0
+        from ..tiles.tiering import cold_sharding
+
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            from ..parallel.rules import BATCH_AXIS, resolve_spec
+            from ..parallel.rules import sharding_for
 
             dev = next(iter(mesh.devices.flat))
             # the slab itself: slot axis over "dp" (the rules table's
             # ``slab`` row), committed so the plain jits run SPMD and the
             # shard_map builder sees matching layouts
-            slab_spec = resolve_spec(PartitionSpec(BATCH_AXIS),
-                                     mesh.axis_names)
             self._hot = jax.device_put(self._hot,
-                                       NamedSharding(mesh, slab_spec))
+                                       sharding_for("slab", mesh))
             # single rows (promotions, handoff imports) replicate over the
             # mesh — a row committed to one chip cannot feed a jit whose
             # other operand spans eight
             self._default_sharding = NamedSharding(mesh, PartitionSpec())
-            try:
-                self._cold_sharding = NamedSharding(
-                    mesh, PartitionSpec(), memory_kind="pinned_host")
-                jax.device_put(jnp.zeros((1,), jnp.float32),
-                               self._cold_sharding)
-                self.cold_memory_kind = "pinned_host"
-            except Exception:  # noqa: BLE001 - backend without host offload
-                self._cold_sharding = self._default_sharding
-                kind = getattr(dev, "default_memory", lambda: None)()
-                self.cold_memory_kind = getattr(kind, "kind", "device")
-                if dev.platform != "cpu":
-                    log.warning(
-                        "session arena: backend %s lacks pinned_host "
-                        "memory; cold beam pages are %s-resident",
-                        dev.platform, self.cold_memory_kind)
         else:
             dev = jax.devices()[0]
-            # cold pages prefer the backend's pinned-host space (the
-            # tiering _put_pages idiom); the CPU backend's default memory
-            # IS host DRAM, so the fallback twin is semantically
-            # identical there
-            try:
-                self._cold_sharding = jax.sharding.SingleDeviceSharding(
-                    dev, memory_kind="pinned_host")
-                jax.device_put(jnp.zeros((1,), jnp.float32),
-                               self._cold_sharding)
-                self.cold_memory_kind = "pinned_host"
-            except Exception:  # noqa: BLE001 - backend without host offload
-                self._cold_sharding = jax.sharding.SingleDeviceSharding(dev)
-                kind = getattr(dev, "default_memory", lambda: None)()
-                self.cold_memory_kind = getattr(kind, "kind", "device")
-                if dev.platform != "cpu":
-                    log.warning(
-                        "session arena: backend %s lacks pinned_host "
-                        "memory; cold beam pages are %s-resident",
-                        dev.platform, self.cold_memory_kind)
             self._default_sharding = jax.sharding.SingleDeviceSharding(dev)
+        # cold pages in pinned host memory where the backend has it
+        # (the tiering placement rule)
+        self._cold_sharding, self.cold_memory_kind = cold_sharding(
+            self._default_sharding, dev, "session arena")
         # donated buffers the backend cannot reuse (CPU) warn per
         # dispatch; the donation is still correct, just not a win there
         warnings.filterwarnings(

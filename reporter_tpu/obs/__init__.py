@@ -16,7 +16,7 @@
               jax.named_scope labels parsed out of profiler captures
               (GET /debug/attrib, bench.py's ``attrib`` block, the
               reporter_stage_device_seconds gauges) plus the shared
-              roofline/row accounting and last_onchip provenance
+              roofline/row accounting against the device kind's HBM peak
 ``quantile``  ONE implementation of histogram-quantile math (Prometheus
               semantics) + the shared SLO_BUCKETS_S log-bucket table —
               used by the SLO engine, tools/trace_top.py and

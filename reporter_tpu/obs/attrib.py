@@ -25,9 +25,9 @@ item 1):
                        every program at its first dispatch with abstract
                        ShapeDtypeStruct args, so nothing is pinned).
   roofline_block() the rows/rep + est-gather-GB/s + hbm_frac accounting
-                   the probe tools and bench.py previously each duplicated
-  last_onchip()    provenance of the newest VERIFIED on-chip capture under
-                   docs/measurements/ (was bench.py._last_onchip)
+                   the probe tools and bench.py previously each duplicated,
+                   against the device kind's published HBM peak
+                   (HBM_PEAK_GBS)
 
 Surfaces: ``reporter_stage_device_seconds{stage}`` +
 ``reporter_attrib_age_seconds`` gauges, ``GET /debug/attrib``
@@ -413,9 +413,30 @@ def executed_rows(n_pairs: int, max_probes: int, dedup: bool = False) -> int:
     return max_probes * (dedup_budget(n_pairs) if dedup else n_pairs)
 
 
+# Published peak HBM bandwidth by ``jax.Device.device_kind``, in GB/s.
+# Source: Google Cloud documentation, "TPU v5e" (16 GB of HBM at
+# 819 GB/s per chip).  A kind missing here is an error, never a default:
+# a roofline share against a guessed peak is not a number.
+HBM_PEAK_GBS = {
+    "TPU v5 lite": 819.0,
+}
+
+
+def hbm_peak_gbs(device_kind: str) -> float:
+    """The published HBM peak of ``device_kind``; ValueError when the
+    table has none."""
+    try:
+        return HBM_PEAK_GBS[device_kind]
+    except KeyError:
+        raise ValueError(
+            "no published HBM peak for device kind %r (add it to "
+            "obs/attrib.HBM_PEAK_GBS with its source)" % (device_kind,)
+        ) from None
+
+
 def roofline_block(n_traces: int, T: int, k: int, secs: float, *,
                    bucket_entries: int, max_probes: int, grid_cap: int,
-                   hbm_gbs: float = 819.0, dedup: bool = False) -> dict:
+                   hbm_gbs: float, dedup: bool = False) -> dict:
     """Estimated useful gather bandwidth for one cohort's kernel rep and
     its fraction of nominal HBM (application-level bytes).  Two dominant
     gather streams per trace: the UBODT transition probes (max_probes
@@ -618,12 +639,11 @@ def capture_matcher(matcher, reps: int = 3, length: Optional[int] = None,
 
 
 def summary() -> dict:
-    """The /statusz line: capture age + headline stage + the last_onchip
-    provenance, so a stale attribution (or a CPU-only one) is visible at
-    a glance next to the serving metrics."""
+    """The /statusz line: capture age + platform + headline stage, so a
+    stale attribution (or a CPU-only one) is visible at a glance next to
+    the serving metrics."""
     res = last()
-    out: dict = {"captured": bool(res), "last_onchip": last_onchip(),
-                 "host": host_summary()}
+    out: dict = {"captured": bool(res), "host": host_summary()}
     if res:
         out.update({
             "age_s": round(time.time() - res.get("captured_unix", 0), 1),
@@ -649,73 +669,12 @@ def repo_root() -> str:
         os.path.abspath(__file__))))
 
 
-_ONCHIP_CACHE: list = []
-
-
-def last_onchip(repo: Optional[str] = None, refresh: bool = False):
-    """Provenance block for the newest VERIFIED on-chip capture under
-    docs/measurements/ (platform "tpu" only): file path, capture date, git
-    hash, and the headline numbers.  Embedded in every bench.py line and
-    the /statusz attrib summary so a stale headline is visible at a
-    glance.  Returns None when no on-chip capture exists.  Cached per
-    process (the measurements bank changes only at commit time)."""
-    if _ONCHIP_CACHE and not refresh and repo is None:
-        return _ONCHIP_CACHE[0]
-    import subprocess
-
-    repo = repo or repo_root()
-    best = None
-    for path in glob.glob(os.path.join(repo, "docs", "measurements", "*.json")):
-        try:
-            with open(path) as f:
-                d = json.load(f)
-        except (OSError, json.JSONDecodeError, UnicodeDecodeError):
-            continue
-        if d.get("platform") != "tpu" or d.get("value") is None:
-            continue
-        m = re.search(r"(\d{4}-\d{2}-\d{2})", os.path.basename(path))
-        # capture date from the filename (checkout resets mtimes); within
-        # one day, the best headline — same-day captures are the same build
-        # at different operating points, and the provenance block should
-        # carry the one the round's claims rest on
-        key = (m.group(1) if m else "", float(d.get("value") or 0))
-        if best is None or key > best[0]:
-            best = (key, path, d)
-    if best is None:
-        out = None
-    else:
-        key, path, d = best
-        git_hash = None
-        try:
-            git_hash = subprocess.run(
-                ["git", "rev-parse", "--short", "HEAD"], cwd=repo,
-                stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, timeout=10,
-            ).stdout.decode().strip() or None
-        except (OSError, subprocess.SubprocessError):
-            pass
-        out = {
-            "file": os.path.relpath(path, repo),
-            "captured": key[0] or None,
-            "git": git_hash,
-            "traces_per_sec": d.get("value"),
-            "points_per_sec": d.get("points_per_sec"),
-            "vs_baseline": d.get("vs_baseline"),
-            "device_util": d.get("device_util"),
-            "kernel_by_cohort": d.get("kernel_by_cohort"),
-        }
-    del _ONCHIP_CACHE[:]
-    _ONCHIP_CACHE.append(out)
-    return out
-
-
 def archive(block: dict, platform: str, repo: Optional[str] = None) -> Optional[str]:
     """Write an attribution artifact under docs/measurements/ as
     ``attrib_<platform>_<date>[_<replica>].json`` and return its
     repo-relative path.  The replica tag ($REPORTER_REPLICA_ID, when set)
     keeps N fleet replicas sharing a checkout from clobbering one
-    another's same-day archives.  The artifact deliberately carries no
-    ``value`` key, so the last_onchip() scan (platform "tpu" AND a
-    headline value) can never mistake it for a bench capture.  Returns
+    another's same-day archives.  Returns
     None when the measurements bank is absent (installed-package
     deployments)."""
     repo = repo or repo_root()

@@ -123,7 +123,9 @@ def _bucket_rows(u, b: jnp.ndarray, valid=None) -> jnp.ndarray:
     """One bucket-row fetch [..., 128 or 256] — a plain gather from a
     device-resident packed table, or the hot-arena / host-paged two-tier
     path when the table is tiered (tiles/tiering.py: bit-identical rows
-    either way, only the executed memory traffic changes).  ``valid``
+    either way, only the executed memory traffic changes; the host pages
+    meet the device-memory index operand only after an explicit move into
+    device memory, which is what JAX 0.9 accepts).  ``valid``
     (None = all) marks which probes are real: the gp-sharded probe clamps
     remote buckets to a local index and masks the rows afterwards, and
     the tiered path must neither count those phantom probes in its EWMA

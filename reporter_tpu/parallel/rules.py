@@ -57,20 +57,12 @@ GRAPH_AXIS = "gp"
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """jax.shard_map across jax versions: newer builds carry it at the
-    top level (``check_vma``), older ones under jax.experimental
-    (``check_rep``).  Every shard_map in this codebase goes through this
-    shim — it is what lets the mesh suites run on builds where the
-    top-level alias does not exist yet."""
+    """``jax.shard_map`` with this codebase's default of no
+    varying-manual-axes check (every shard_map goes through here)."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 # the one table.  Order matters: first match wins.
 PROGRAM_RULES: Tuple[Tuple[str, P], ...] = (

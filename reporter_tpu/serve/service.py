@@ -231,11 +231,10 @@ class MicroBatcher:
     -- so size max_device_points for (max_inflight + 2) * PIPELINE_DEPTH
     chunks, not PIPELINE_DEPTH alone.  At the defaults (depth 8,
     max_inflight 4, ~3.7 MB of packed transport per chunk) that composite
-    is ~178 MB of HBM next to the graph + UBODT.  Depth 4 is the measured
-    v5e optimum: it hides every dispatch sync quantum and the whole of
-    host association under device compute (e2e 3116 vs 2321 tr/s at
-    depth 2, device_util 1.0 vs 0.87 --
-    docs/measurements/bench_tpu_2026-07-31_inflight4.json).
+    is ~178 MB of HBM next to the graph + UBODT.  Depth 4 hid the
+    dispatch sync cost and the whole of host association under device
+    compute in round 5's chip sweep (depth 2/3/4; a capture deleted in
+    PR 21 with its access path — re-measure in the benchmark).
 
     Fault domains (docs/robustness.md): the submit queue is BOUNDED and
     sheds at admission (Overloaded -> 429), every entry carries a deadline
@@ -260,8 +259,8 @@ class MicroBatcher:
                  quarantine_ttl_s: Optional[float] = None,
                  on_wedged=None, on_crashed=None, name: str = "batch"):
         if max_inflight is None:
-            # 4 = measured v5e optimum (hides every dispatch sync quantum
-            # and all host association under device compute); when the
+            # 4 on an accelerator (round 5's depth sweep: hides host
+            # association under device compute); when the
             # compute actually runs on host cores (the numpy cpu backend,
             # or the jax backend on cpu devices) it shares them with
             # association and deep pipelining only adds contention --
@@ -1754,10 +1753,10 @@ class ReporterService:
             return 500, {"error": str(e)}
 
     def handle_statusz(self) -> Tuple[int, dict]:
-        """JSON ops snapshot: uptime + config + bucket tables + every metric
-        family (the dict form of /metrics, for humans and scripts).  The
-        ``attrib`` line carries the last capture's age and top stage plus
-        the ``last_onchip`` provenance, so a stale (or CPU-only)
+        """JSON ops snapshot: uptime + config + the engine's device +
+        bucket tables + every metric family (the dict form of /metrics,
+        for humans and scripts).  The ``attrib`` line carries the last
+        capture's age, platform and top stage, so a stale (or CPU-only)
         attribution headline is visible at a glance."""
         from ..obs import attrib as obs_attrib
 
@@ -1769,6 +1768,9 @@ class ReporterService:
             "draining": bool(self.draining),
             "warming": bool(getattr(self, "warming", False)) or m is None,
             "backend": m.backend if m else None,
+            # the engine's devices as JAX reports them: platform,
+            # device_kind, count, mesh shape, per-state placement
+            "device": m.device_summary() if m else None,
             "viterbi_kernel": getattr(m, "_kernel_mode", None) if m else None,
             "threshold_sec": self.threshold_sec,
             "batch": dict(self._batch_params),

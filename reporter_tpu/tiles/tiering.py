@@ -118,6 +118,23 @@ def shard_bucket_range(idx: int, n_shards: int,
     return lo, hi
 
 
+def cold_sharding(sharding, dev, owner: str):
+    """(``sharding`` in the backend's ``pinned_host`` memory, that kind),
+    or ``sharding`` unchanged with ``dev``'s default memory kind where the
+    backend has no pinned host memory — host DRAM itself on the CPU
+    backend; on an accelerator a capacity concession, logged.  The cold
+    tiers of the UBODT and of the session arena both place through here;
+    their pages reach a program only after an explicit move into device
+    memory."""
+    if any(m.kind == "pinned_host" for m in dev.addressable_memories()):
+        return sharding.with_memory_kind("pinned_host"), "pinned_host"
+    kind = dev.default_memory().kind
+    if dev.platform != "cpu":
+        log.warning("%s: backend %s lacks pinned_host memory; cold pages "
+                    "are %s-resident", owner, dev.platform, kind)
+    return sharding, kind
+
+
 class TieredDeviceUBODT:
     """The device-side face of a tiered table: pytree whose leaves are the
     hot arena + slot map, with (bmask, layout, manager) as static aux —
@@ -297,63 +314,27 @@ class TieredTable:
                                  self)
 
     def _put_pages(self):
-        """The cold pages as one device-visible array, preferring the
-        backend's pinned-host memory space (XLA host offload: the bytes
-        stay in host DRAM, a cold gather pays the interconnect, and
-        device memory holds only the arena).  Falls back to the default
-        memory space — on the CPU backend that IS host memory, so the
-        fallback is the semantically-identical twin; on an accelerator
-        without host offload it is a capacity concession, logged."""
+        """The cold pages as one array in host memory where the backend
+        has it (XLA host offload: the bytes stay in host DRAM and device
+        memory holds only the arena between misses)."""
         import jax
-        import jax.numpy as jnp
 
         if self.mesh is not None:
             dev = next(iter(self.mesh.devices.flat))
-            try:
-                pages = jax.device_put(
-                    self.pages, self._leaf_sharding("pinned_host"))
-                return pages, "pinned_host"
-            except Exception:  # noqa: BLE001 - backend without host offload
-                kind = getattr(dev, "default_memory", lambda: None)()
-                kind = getattr(kind, "kind", "device")
-                if dev.platform != "cpu":
-                    log.warning(
-                        "ubodt tiering: backend %s lacks pinned_host "
-                        "memory; cold pages are %s-resident (capacity "
-                        "win deferred to a host-offload-capable jax)",
-                        dev.platform, kind)
-                return jax.device_put(self.pages,
-                                      self._leaf_sharding()), kind
-        dev = jax.devices()[0]
-        try:
-            sharding = jax.sharding.SingleDeviceSharding(
-                dev, memory_kind="pinned_host")
-            pages = jax.device_put(self.pages, sharding)
-            return pages, "pinned_host"
-        except Exception:  # noqa: BLE001 - backend without host offload
-            kind = getattr(dev, "default_memory", lambda: None)()
-            kind = getattr(kind, "kind", "device")
-            if dev.platform != "cpu":
-                log.warning(
-                    "ubodt tiering: backend %s lacks pinned_host memory; "
-                    "cold pages are %s-resident (capacity win deferred "
-                    "to a host-offload-capable jax)", dev.platform, kind)
-            return jnp.asarray(self.pages), kind
+            sharding = self._leaf_sharding()
+        else:
+            dev = jax.devices()[0]
+            sharding = jax.sharding.SingleDeviceSharding(dev)
+        sharding, kind = cold_sharding(sharding, dev, "ubodt tiering")
+        return jax.device_put(self.pages, sharding), kind
 
-    def _leaf_sharding(self, memory_kind: Optional[str] = None):
+    def _leaf_sharding(self):
         """The rule table's placement for a tier leaf on this mesh:
         bucket-range over "gp" (axis 0) when the mesh carries that axis,
         replicated otherwise (parallel/rules.py: the du rule)."""
-        import jax
+        from ..parallel.rules import sharding_for
 
-        from ..parallel.rules import GRAPH_AXIS, resolve_spec
-
-        spec = resolve_spec(jax.sharding.PartitionSpec(GRAPH_AXIS),
-                            self.mesh.axis_names)
-        if memory_kind is None:
-            return jax.sharding.NamedSharding(self.mesh, spec)
-        return jax.sharding.NamedSharding(self.mesh, spec,
-                                          memory_kind=memory_kind)
+        return sharding_for("du", self.mesh)
 
     def _build_hot(self, hot_set: np.ndarray):
         """(arena, slot_map) device arrays for a hot bucket set.  The
@@ -541,15 +522,23 @@ def tiered_bucket_rows(u: TieredDeviceUBODT, b, valid=None):
 
     The exact lax.cond full-width fallback pattern of the PR 5 dedup
     overflow: predicate = "every probed bucket is hot".  True: one arena
-    gather, the cold pages are never touched.  False: the FULL bucket
-    set gathers from the host-memory pages and a per-element select
-    keeps the arena rows where they exist.  Both sides produce identical
-    bytes (arena rows are copies of the pages), so downstream selects —
-    and therefore match output — are bit-identical to an untiered table.
-    Probe-frequency accounting rides a park-only debug.callback OUTSIDE
-    the data path.  Under vmap (the carry/session seam transitions) the
-    cond lowers to a select and both sides execute — correctness is
-    unaffected; only the fast-path skip is.
+    gather, the cold pages are never touched.  False: the cold pages move
+    EXPLICITLY into device memory (a gather takes all its operands from
+    one memory space), the FULL bucket set gathers from them, and a
+    per-element select keeps the arena rows where they exist.  Both sides
+    produce identical bytes (arena rows are copies of the pages), so
+    downstream selects — and therefore match output — are bit-identical
+    to an untiered table.  The move stages the local page table in device
+    memory for the length of the fallback, so the capacity bound is the
+    page table's size, not only the arena's.  Probe-frequency accounting
+    rides a park-only debug.callback OUTSIDE the data path.
+
+    Under vmap (the carry/session seam transitions) the fetch is NOT
+    vmapped as a cond — that would turn into a select over every operand,
+    host pages included, which JAX refuses: a ``custom_vmap`` rule runs
+    the fetch once over the batched indices instead (it is shape-
+    polymorphic in the leading dims), so the fast-path skip holds there
+    too.
 
     ``valid`` (None = all) marks which probes are real: under the
     gp-sharded probe remote buckets arrive clamped to local index 0 with
@@ -558,6 +547,12 @@ def tiered_bucket_rows(u: TieredDeviceUBODT, b, valid=None):
     drain instead of polluting bucket 0's EWMA.  Stats carry GLOBAL
     bucket ids (local + axis_index * L), so the manager's counters mean
     the same thing sharded or not."""
+    if valid is None:
+        return _rows_all(u, b)
+    return _rows_masked(u, b, valid)
+
+
+def _tiered_rows(u: TieredDeviceUBODT, b, valid):
     import jax
     import jax.numpy as jnp
 
@@ -585,7 +580,44 @@ def tiered_bucket_rows(u: TieredDeviceUBODT, b, valid=None):
 
     def _paged(_):
         with stage("tier-page"):
-            rows_cold = pages[b]
+            rows_cold = jax.device_put(pages, jax.memory.Space.Device)[b]
         return jnp.where(hot[..., None], rows_hot, rows_cold)
 
     return jax.lax.cond(jnp.all(hot_eff), _all_hot, _paged, None)
+
+
+def _batched_rule(fn):
+    """custom_vmap rule: broadcast unbatched index operands and call
+    ``fn`` once on the batch (tier leaves are never batched)."""
+    def rule(axis_size, in_batched, u, *arrays):
+        import jax
+        import jax.numpy as jnp
+
+        if any(jax.tree_util.tree_leaves(in_batched[0])):
+            raise NotImplementedError("tiered table leaves cannot be vmapped")
+        arrays = [a if bat else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                  for a, bat in zip(arrays, in_batched[1:])]
+        return fn(u, *arrays), True
+    return rule
+
+
+def _make_fetches():
+    from jax import custom_batching
+
+    @custom_batching.custom_vmap
+    def rows_all(u, b):
+        return _tiered_rows(u, b, None)
+
+    @custom_batching.custom_vmap
+    def rows_masked(u, b, valid):
+        return _tiered_rows(u, b, valid)
+
+    rows_all.def_vmap(_batched_rule(rows_all))
+    rows_masked.def_vmap(_batched_rule(rows_masked))
+    return rows_all, rows_masked
+
+
+try:
+    _rows_all, _rows_masked = _make_fetches()
+except ImportError:  # pragma: no cover - host-only usage without jax
+    pass

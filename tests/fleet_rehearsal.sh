@@ -74,7 +74,7 @@ export REPORTER_FAULT_ROUTER_CONNECT="refused:1"
 export REPORTER_FAULT_REPLICA_SHED="1"
 # replicas 2..N replay replica 1's XLA compiles instead of redoing them
 reh_init "${1:-}" reporter-fleet
-export REPORTER_XLA_CACHE_DIR="$WORK/xla-cache"
+export JAX_COMPILATION_CACHE_DIR="$WORK/xla-cache"
 ROUTER_PORT=18071
 BASE_PORT=18072
 echo "fleet rehearsal workdir: $WORK"

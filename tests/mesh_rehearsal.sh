@@ -49,7 +49,7 @@ export REPORTER_SLO_P999_MS=0
 export REPORTER_SLO_DEGRADED_FRAC=0
 export REPORTER_SLO_STREAM_P99_MS=4000
 reh_init "${1:-}" reporter-mesh
-export REPORTER_XLA_CACHE_DIR="$WORK/xla-cache"
+export JAX_COMPILATION_CACHE_DIR="$WORK/xla-cache"
 ROUTER_PORT=18281
 BASE_PORT=18282
 echo "mesh rehearsal workdir: $WORK (dp-8 replica, arena+tiering+sparse ON)"

@@ -63,7 +63,7 @@ export REPORTER_FAULT_DEVICE_HANG="0.15"
 export REPORTER_COST_PER_CHIP_HOUR=3.60
 export REPORTER_HISTORY_TICK_S=0.5
 reh_init "${1:-}" reporter-overload
-export REPORTER_XLA_CACHE_DIR="$WORK/xla-cache"
+export JAX_COMPILATION_CACHE_DIR="$WORK/xla-cache"
 ROUTER_PORT=18091
 BASE_PORT=18092
 ROUTER_PORT_C=18097

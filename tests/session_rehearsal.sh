@@ -69,7 +69,7 @@ export REPORTER_SLO_STREAM_P99_MS=2500
 # sessions and must not move a bit
 export REPORTER_SESSION_ARENA=1
 reh_init "${1:-}" reporter-session
-export REPORTER_XLA_CACHE_DIR="$WORK/xla-cache"
+export JAX_COMPILATION_CACHE_DIR="$WORK/xla-cache"
 ROUTER_PORT=18081
 BASE_PORT=18082
 echo "session rehearsal workdir: $WORK"

@@ -36,7 +36,7 @@ export REPORTER_SLO_P99_MS=8000
 export REPORTER_SLO_P999_MS=0
 export REPORTER_SLO_DEGRADED_FRAC=0
 reh_init "${1:-}" reporter-shard
-export REPORTER_XLA_CACHE_DIR="$WORK/xla-cache"
+export JAX_COMPILATION_CACHE_DIR="$WORK/xla-cache"
 ROUTER_PORT=18181
 BASE_PORT=18182
 echo "shard rehearsal workdir: $WORK (hot budget $REPORTER_UBODT_HOT_BYTES B)"

@@ -649,3 +649,35 @@ def test_geo_on_prefers_shard_owner(monkeypatch):
         assert router._geo_pref(junk, cell) == 0
     finally:
         router.stop()
+
+
+def _fleet_tool():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "fleet_tool", os.path.join(REPO, "tools", "fleet.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_accelerator_replicas_take_one_chip_each(tmp_path, monkeypatch):
+    """Without --cpu-default each replica owns one chip; more replicas
+    than visible chips is refused before anything spawns (checked on the
+    CPU: the chips are named through TPU_VISIBLE_CHIPS)."""
+    fleet = _fleet_tool()
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setenv("TPU_VISIBLE_CHIPS", "0,1")
+    argv = ["--config", str(tmp_path / "c.json"), "--workdir",
+            str(tmp_path / "w")]
+    f = fleet.Fleet(fleet.build_parser().parse_args(argv + ["--replicas", "2"]))
+    assert [c.chip for c in f.replicas] == [0, 1]
+    assert [c.env["TPU_VISIBLE_CHIPS"] for c in f.replicas] == ["0", "1"]
+    with pytest.raises(RuntimeError, match="no free chip"):
+        f._make_replica()
+    with pytest.raises(SystemExit, match="2 chip"):
+        fleet.Fleet(fleet.build_parser().parse_args(
+            argv + ["--replicas", "3"]))
+    cpu = fleet.Fleet(fleet.build_parser().parse_args(
+        argv + ["--replicas", "3", "--cpu-default"]))
+    assert all(c.chip is None for c in cpu.replicas)

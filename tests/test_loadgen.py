@@ -304,7 +304,7 @@ def test_main_artifact_schema_and_perf_gate_consumable(loadgen, tmp_path):
     art = json.loads(out.read_text())
     # perf_gate header keys (docs/bench-schema.md shape)
     for key in ("metric", "value", "unit", "platform", "attrib",
-                "last_onchip", "attrib_reason"):
+                "attrib_reason"):
         assert key in art, key
     assert art["edges"] == 80  # picked up from /health
     assert art["requests"] >= 1 and art["status"].get("200")

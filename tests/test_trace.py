@@ -208,9 +208,9 @@ def test_json_log_attaches_current_trace_id():
 
 def test_event_fields_json_and_text():
     logger, stream = _capture_logger("json")
-    obs_log.event(logger, "relay_probe", open=False, ports=[], skipme=None)
+    obs_log.event(logger, "door_probe", open=False, ports=[], skipme=None)
     line = json.loads(stream.getvalue().strip())
-    assert line["event"] == "relay_probe"
+    assert line["event"] == "door_probe"
     assert line["open"] is False and line["ports"] == []
     assert "skipme" not in line  # None fields dropped
 

@@ -57,10 +57,10 @@ def _trace(arrays, n=10, uuid="wm"):
 
 
 def test_warmed_server_first_request_sees_no_compile_stall(engine, tmp_path, monkeypatch):
-    """serve --warmup + REPORTER_XLA_CACHE_DIR: after the warm pass, the
+    """serve --warmup + JAX_COMPILATION_CACHE_DIR: after the warm pass, the
     first real request of every configured bucket records zero new
     compile_stall events — across a simulated restart too."""
-    monkeypatch.setenv("REPORTER_XLA_CACHE_DIR", str(tmp_path / "xla"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     from reporter_tpu.utils.jaxenv import enable_compilation_cache
 
     assert enable_compilation_cache() == str(tmp_path / "xla")

@@ -11,6 +11,10 @@ supervisor's restart loop is the herd's continuity.  A state file
 harness (tests/fleet_rehearsal.sh) can SIGKILL a specific replica and
 watch the fleet absorb it.
 
+Replicas on an accelerator (no `--cpu-default`, no `JAX_PLATFORMS=cpu`)
+take one chip each (`TPU_VISIBLE_CHIPS`), and the supervisor refuses more
+of them than it sees chips: a chip belongs to one process.
+
 The supervisor is ALSO a federation point (obs/federation.py, the same
 machinery the router's `GET /metrics` serves): it pulls every replica's
 mergeable metrics snapshot on an interval and writes
@@ -70,6 +74,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import logging
 import os
@@ -119,6 +124,28 @@ def _post_json(url: str, payload: dict, timeout: float = 30.0):
         return json.loads(r.read().decode())
 
 
+def visible_chips(env: dict) -> list:
+    """Chip indices accelerator replicas may take, read WITHOUT touching
+    JAX (a supervisor that initialised the TPU would hold every chip):
+    ``$TPU_VISIBLE_CHIPS`` when set, else one index per accelerator
+    device node on the host."""
+    raw = env.get("TPU_VISIBLE_CHIPS", "").strip()
+    if raw:
+        return [int(c) for c in raw.split(",") if c.strip()]
+    for pattern in ("/dev/accel[0-9]*", "/dev/vfio/[0-9]*"):
+        nodes = glob.glob(pattern)
+        if nodes:
+            return list(range(len(nodes)))
+    return []
+
+
+def chip_env(chip: int) -> dict:
+    """The environment that confines one serve process to one chip."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1"}
+
+
 class Child:
     """One supervised process (replica or router)."""
 
@@ -130,6 +157,7 @@ class Child:
         self.log_path = log_path
         self.url = url
         self.rid = rid                  # replica id (None for the router)
+        self.chip = None                # the accelerator chip it owns
         self.proc: subprocess.Popen = None
         self.restarts = 0
         self.expected_exit = False  # set around intentional drains
@@ -194,6 +222,16 @@ class Fleet:
                         os.path.join(self.workdir, "history"))
         self.history_dir = base["REPORTER_HISTORY_DIR"]
         self._base_env = base
+        # accelerator replicas (no --cpu-default, no JAX_PLATFORMS=cpu):
+        # one chip each — a chip belongs to one process
+        self._chips = None
+        if base.get("JAX_PLATFORMS", "") != "cpu":
+            self._chips = visible_chips(base)
+            if args.replicas > len(self._chips):
+                raise SystemExit(
+                    "fleet: %d accelerator replicas asked for but %d chip(s) "
+                    "visible (one chip per replica; --cpu-default serves "
+                    "from the CPU)" % (args.replicas, len(self._chips)))
         self.replicas = []
         self._next_idx = 0
         self._next_port = args.base_port
@@ -256,11 +294,22 @@ class Fleet:
         if self.args.ubodt_shards > 0:
             env["REPORTER_UBODT_SHARD"] = "%d/%d" % (
                 i % self.args.ubodt_shards, self.args.ubodt_shards)
-        return Child(
+        chip = None
+        if self._chips is not None:
+            taken = {c.chip for c in self.replicas}
+            free = [c for c in self._chips if c not in taken]
+            if not free:
+                raise RuntimeError("no free chip for another accelerator "
+                                   "replica (%d visible)" % len(self._chips))
+            chip = free[0]
+            env.update(chip_env(chip))
+        c = Child(
             rid,
             serve_cmd + [self.args.config, "%s:%d" % (self.host, port)],
             env, os.path.join(self.workdir, "replica-%d.log" % i),
             "http://%s:%d" % (self.host, port), rid=rid)
+        c.chip = chip
+        return c
 
     # -- state file ---------------------------------------------------------
 
@@ -610,7 +659,11 @@ class Fleet:
         cooldown must start from a fleet that is actually bigger)."""
         with self._scaling:
             with self._lock:
-                c = self._make_replica()
+                try:
+                    c = self._make_replica()
+                except RuntimeError as e:  # every chip already serves
+                    log.error("scale-up refused: %s", e)
+                    return False
                 self.replicas.append(c)
                 c.spawn()
                 self.write_state()
@@ -752,10 +805,7 @@ class Fleet:
             self._rolling.clear()
 
 
-def main(argv=None) -> int:
-    logging.basicConfig(
-        level=logging.INFO,
-        format="%(asctime)s fleet %(levelname)s %(message)s")
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(
         description=__doc__.splitlines()[0],
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -767,7 +817,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workdir", default="/tmp/reporter-fleet")
     ap.add_argument("--warmup", action="store_true",
                     help="boot each replica with --warmup (share "
-                         "REPORTER_XLA_CACHE_DIR so replicas 2..N replay "
+                         "JAX_COMPILATION_CACHE_DIR so replicas 2..N replay "
                          "replica 1's compiles)")
     ap.add_argument("--up-timeout", type=float, default=240.0,
                     help="seconds to wait for a replica to become healthy")
@@ -817,8 +867,14 @@ def main(argv=None) -> int:
     ap.add_argument("--session-checkpoint-sync", action="store_true",
                     help="checkpoint each session commit synchronously "
                          "(zero lost answered points under SIGKILL)")
-    args = ap.parse_args(argv)
-    return Fleet(args).run()
+    return ap
+
+
+def main(argv=None) -> int:
+    logging.basicConfig(
+        level=logging.INFO,
+        format="%(asctime)s fleet %(levelname)s %(message)s")
+    return Fleet(build_parser().parse_args(argv)).run()
 
 
 if __name__ == "__main__":

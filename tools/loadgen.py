@@ -27,7 +27,7 @@ disagreement, which is what makes the CI ``slo-rehearsal`` leg gating.
 One JSON artifact (stdout or ``--out``): schedule + achieved rate,
 status breakdown, p50/p95/p99/p99.9, per-step ramp table and knee,
 client + server SLO verdicts.  Schema-complete for tools/perf_gate.py
-(metric/value/unit/platform + attrib/last_onchip keys).
+(metric/value/unit/platform + attrib keys).
 
 Usage (synth fleet, 30 req/s for 10 s):
     python tools/loadgen.py --url http://localhost:8002 \
@@ -1017,7 +1017,6 @@ def main(argv=None) -> int:
         "edges": health.get("edges"),
         "attrib": None,
         "attrib_reason": "loadgen artifact (no profiler capture)",
-        "last_onchip": None,
         # the run itself
         "url": base,
         "arrival": args.arrival,

@@ -51,12 +51,12 @@ import math
 import sys
 
 # keys every emitted bench line must carry (docs/bench-schema.md).  The
-# round-6 keys (last_onchip, attrib — attrib may be null but the KEY must
-# exist, with an attrib_reason when null) are asserted with
+# round-6 attrib key (it may be null but the KEY must exist, with an
+# attrib_reason when null) is asserted with
 # --require-attrib, which the CI leg sets; pre-round-6 history predates
 # them and must stay judgeable.
 REQUIRED_KEYS = ("metric", "value", "unit", "platform")
-ATTRIB_KEYS = ("last_onchip", "attrib")
+ATTRIB_KEYS = ("attrib",)
 # judged metrics -> the GOOD direction.  Throughput families regress
 # when they DROP; cost families (docs/economics.md — the chip-second
 # ledger's $-per-million-matched-points rides every artifact) regress
@@ -304,7 +304,7 @@ def main(argv=None) -> int:
                          "spread either way)")
     ap.add_argument("--require-attrib", action="store_true",
                     help="assert the round-6 schema on the candidate: "
-                         "last_onchip + attrib keys present (attrib null "
+                         "the attrib key present (attrib null "
                          "only with an attrib_reason) — the CI leg sets "
                          "this")
     args = ap.parse_args(argv)
