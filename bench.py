@@ -756,7 +756,7 @@ def run_device(platform: str, scenario, arrays, ubodt, cohorts) -> dict:
     # (c) host_frac: a dedicated attribution capture through the REAL
     #     match_many path (the per-cohort captures above run raw jitted
     #     fns, which accrue no host stages), so the artifact's headline
-    #     host share covers pack/dispatch/collect of live dispatches.
+    #     host share covers pack/dispatch/associate of live dispatches.
     host_pipeline = None
     if os.environ.get("BENCH_HOST_PIPELINE", "1").lower() not in (
             "0", "false", "no", "off"):

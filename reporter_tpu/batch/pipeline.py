@@ -46,6 +46,7 @@ from typing import Callable, Iterable, List, Optional, Sequence, Tuple
 
 from ..anonymise.storage import make_store
 from ..utils import journal
+from ..obs import attrib as obs_attrib
 from ..obs import flight as obs_flight
 from ..obs import metrics as obs
 from ..obs import trace as obs_trace
@@ -389,32 +390,34 @@ def make_matches(
         # the native parser skips torn rows (concurrent phase-1 appends can
         # tear a line mid-write); so does its Python fallback.  Shards are
         # read in bounded chunks so a multi-GB archive doesn't spike memory.
-        traces: dict = {}
-        skipped = 0
-        for uuids, tms, lats, lons, accs, chunk_lines in _iter_shard_chunks(file_name):
-            skipped += chunk_lines - len(uuids)
-            for i in range(len(uuids)):
-                traces.setdefault(uuids[i], []).append(
-                    {
-                        "lat": float(lats[i]),
-                        "lon": float(lons[i]),
-                        "time": int(tms[i]),
-                        "accuracy": int(accs[i]),
-                    }
-                )
-        if skipped:
-            C_ROWS_SKIPPED.inc(skipped)
-            log.warning("skipped %d malformed row(s) in %s", skipped, file_name)
+        with obs_attrib.host_stage("read"):
+            traces: dict = {}
+            skipped = 0
+            for uuids, tms, lats, lons, accs, chunk_lines in \
+                    _iter_shard_chunks(file_name):
+                skipped += chunk_lines - len(uuids)
+                for i in range(len(uuids)):
+                    traces.setdefault(uuids[i], []).append(
+                        {
+                            "lat": float(lats[i]),
+                            "lon": float(lons[i]),
+                            "time": int(tms[i]),
+                            "accuracy": int(accs[i]),
+                        }
+                    )
+            if skipped:
+                C_ROWS_SKIPPED.inc(skipped)
+                log.warning("skipped %d malformed row(s) in %s", skipped,
+                            file_name)
 
-        # build every match request up front; competing phase-1 appends are
-        # repaired by the sort (simple_reporter.py:145-146)
-        requests = []
-        for uuid, points in traces.items():
-            points.sort(key=lambda v: v["time"])
-            for window in _windows(points, inactivity):
-                requests.append(
-                    {"uuid": uuid, "trace": window, "match_options": {"mode": mode}}
-                )
+            # build every match request up front; competing phase-1
+            # appends are repaired by the sort (simple_reporter.py:145-146)
+            requests = []
+            for uuid, points in traces.items():
+                points.sort(key=lambda v: v["time"])
+                for window in _windows(points, inactivity):
+                    requests.append({"uuid": uuid, "trace": window,
+                                     "match_options": {"mode": mode}})
 
         tiles: dict = {}
         matched = 0
@@ -441,38 +444,45 @@ def make_matches(
                 log.error("match micro-batch failed in %s: %s", file_name, e)
                 continue
             t0 = time.monotonic()
-            n_fail = 0
-            for request, match in zip(chunk, matches):
-                try:
-                    rep = report_fn(
-                        match, request, threshold_sec, report_levels, transition_levels, mode
+            # two passes per micro-batch, so report() and the tile fan-out
+            # are each one host stage; rows still land in window order
+            reports = []
+            with obs_attrib.host_stage("report", span, n=len(chunk)):
+                for request, match in zip(chunk, matches):
+                    try:
+                        rep = report_fn(match, request, threshold_sec,
+                                        report_levels, transition_levels, mode)
+                    except (KeyboardInterrupt, SystemExit):
+                        raise
+                    except Exception:
+                        C_REPORT_FAIL.inc()
+                        log.error(
+                            "failed to report trace with uuid %s from file %s",
+                            request["uuid"], file_name,
+                        )
+                        continue
+                    reports.append((request, rep))
+            with obs_attrib.host_stage("tile", span, n=len(reports)):
+                for request, rep in reports:
+                    _bucket_reports(
+                        rep, request, quantisation, source, mode, tiles,
+                        file_name
                     )
-                except (KeyboardInterrupt, SystemExit):
-                    raise
-                except Exception:
-                    C_REPORT_FAIL.inc()
-                    n_fail += 1
-                    log.error(
-                        "failed to report trace with uuid %s from file %s",
-                        request["uuid"], file_name,
-                    )
-                    continue
-                matched += 1
-                C_WINDOWS.inc()
-                _bucket_reports(
-                    rep, request, quantisation, source, mode, tiles, file_name
-                )
+            matched += len(reports)
+            C_WINDOWS.inc(len(reports))
             span.mark("report_fn_s", time.monotonic() - t0)
+            n_fail = len(chunk) - len(reports)
             if n_fail:
                 span.fail("%d/%d windows failed report()" % (n_fail, len(chunk)),
                           status="partial")
             obs_flight.record(span)
 
-        for tile_file, rows in tiles.items():
-            path = os.path.join(dest_dir, tile_file)
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            with open(path, "a") as f:
-                f.write("".join(rows))
+        with obs_attrib.host_stage("tile"):
+            for tile_file, rows in tiles.items():
+                path = os.path.join(dest_dir, tile_file)
+                os.makedirs(os.path.dirname(path), exist_ok=True)
+                with open(path, "a") as f:
+                    f.write("".join(rows))
         log.info("finished matching %d windows in %s", matched, file_name)
     log.info("done matching trace data files")
     return dest_dir

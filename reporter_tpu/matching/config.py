@@ -40,10 +40,10 @@ class MatcherConfig:
     # in-batch probe-pair dedup (same doc section): sort-unique-gather-
     # scatter over the dispatch's packed (src, dst) probe keys inside the
     # jitted program, so each distinct pair pays one row gather per
-    # dispatch (fleet batches measure 4-8x redundant; the
-    # reporter_probe_dedup_ratio gauge / bench probe_dedup field carry the
-    # live number).  Bit-identical output either way — an overflow of the
-    # static unique budget falls back to the plain probe in-program.
+    # dispatch (fleet batches measure 4-8x redundant; the bench
+    # probe_dedup field carries the number).  Bit-identical output either
+    # way — an overflow of the static unique budget falls back to the
+    # plain probe in-program.
     # $REPORTER_PROBE_DEDUP=0|1 overrides at runtime.
     probe_dedup: bool = False
     # hot/cold UBODT tiering (docs/performance.md "Continent-scale data

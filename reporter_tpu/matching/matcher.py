@@ -28,6 +28,7 @@ from .. import faults
 from ..obs import attrib as obs_attrib
 from ..obs import log as obs_log
 from ..obs import metrics as obs
+from ..obs import trace as obs_trace
 from ..tiles.arrays import GraphArrays, build_graph_arrays
 from ..tiles.network import RoadNetwork
 from ..tiles.ubodt import UBODT, build_ubodt
@@ -105,17 +106,6 @@ C_POINTS = obs.counter(
 C_BREAKS = obs.counter(
     "reporter_transition_breaks_total",
     "Points flagged as HMM discontinuities (includes window starts)")
-C_PROBES = obs.counter(
-    "reporter_ubodt_probe_total",
-    "Sampled UBODT transition-probe outcomes (ops/diagnostics.py; enable "
-    "with REPORTER_OBS_PROBE_EVERY=N)",
-    ("outcome",))
-G_DEDUP_RATIO = obs.gauge(
-    "reporter_probe_dedup_ratio",
-    "Sampled in-batch UBODT probe redundancy: probe pairs / distinct "
-    "(src, dst) pairs in the last sampled dispatch — the factor the "
-    "probe-dedup path removes (docs/performance.md; sampled with "
-    "REPORTER_OBS_PROBE_EVERY=N)")
 
 # chunks allowed in flight on the device while the host associates earlier
 # ones.  Each in-flight chunk pins its packed input + result,
@@ -335,22 +325,8 @@ class SegmentMatcher:
         # consumer copies out synchronously (pack_inputs / the cpu oracle),
         # so reuse is safe.
         self._staging: Dict[tuple, np.ndarray] = {}
-        # first-dispatch shape tracking for the compile counters, plus the
-        # sampled device-side probe diagnostic (0 = off, the default: the
-        # probe program doubles device work for its batch, so it is an
-        # every-Nth-dispatch sample, never an always-on cost)
+        # first-dispatch shape tracking for the compile counters
         self._compiled_shapes: set = set()
-        self._dispatch_count = 0
-        try:
-            self._probe_every = int(os.environ.get("REPORTER_OBS_PROBE_EVERY", "0"))
-        except ValueError:
-            self._probe_every = 0
-        self._jit_probe = None
-        # probe results dispatched but not yet fetched: the sampler enqueues
-        # on the dispatch thread and the sync (np.asarray) happens on the
-        # collect side, so a probe tick never lengthens a dispatch
-        self._probe_pending: list = []
-        self._probe_lock = threading.Lock()
         if backend == "jax":
             self._init_jax()
         elif backend == "cpu":
@@ -1043,10 +1019,6 @@ class SegmentMatcher:
             self._note_dispatch(
                 px.shape, _time.monotonic() - t0, kernel=kernel, fn=fn,
                 args=(self._dg, self._du, xin, p, self.cfg.beam_k))
-            if self._probe_every:
-                self._dispatch_count += 1
-                if self._dispatch_count % self._probe_every == 0:
-                    self._record_probe_stats(xin)
             self._start_host_copy(res)
             return ("jax", B, res, aux)
         cpu = self._cpu if not pkey else self._cpu_for(pkey)
@@ -1092,66 +1064,6 @@ class SegmentMatcher:
             if key[0] == kind and key[1] == kernel and key[-1] == T
         )
 
-    def _record_probe_stats(self, xin) -> None:
-        """Sampled ops/diagnostics.ubodt_probe_stats over an already-packed
-        device batch.  DISPATCH ONLY on this (hot) thread: the program is
-        enqueued asynchronously and the device handle parked on
-        _probe_pending; the blocking np.asarray happens on the collect side
-        (_harvest_probe_stats, called from _collect_batch, where the caller
-        is already paying a device sync).  Any failure disables the sampler
-        (diagnostic only; e.g. the gp-sharded table needs the shard_map path
-        the plain probe program does not speak)."""
-        try:
-            if self._jit_probe is None:
-                import functools
-
-                import jax
-
-                from ..ops.diagnostics import ubodt_probe_stats
-
-                self._jit_probe = jax.jit(
-                    functools.partial(
-                        ubodt_probe_stats, delta=float(self.cfg.ubodt_delta)),
-                    static_argnums=(4,))
-            res = self._jit_probe(
-                self._dg, self._du, xin, self._params, self.cfg.beam_k)
-            with self._probe_lock:
-                self._probe_pending.append(res)
-                # bound pinned probe results: if no collect ran between two
-                # probe ticks, drain the older one here (still off the
-                # common case's hot path)
-                drain = (self._probe_pending[:-1]
-                         if len(self._probe_pending) > 2 else [])
-                if drain:
-                    del self._probe_pending[:-1]
-            for res in drain:
-                self._consume_probe(res)
-        except Exception:  # noqa: BLE001 - never fail a dispatch over a sample
-            log.exception("ubodt probe sampling failed; disabling")
-            self._probe_every = 0
-
-    def _consume_probe(self, res) -> None:
-        stats = np.asarray(res)
-        for i, outcome in enumerate(
-                ("pairs", "miss", "costly_miss", "beyond_delta")):
-            C_PROBES.labels(outcome).inc(int(stats[i]))
-        # [4] = distinct (src, dst) pairs: pairs/distinct is the in-batch
-        # probe redundancy the dedup path removes
-        if len(stats) > 4 and int(stats[4]) > 0:
-            G_DEDUP_RATIO.set(int(stats[0]) / int(stats[4]))
-
-    def _harvest_probe_stats(self) -> None:
-        """Collect-side drain of dispatched probe programs (the np.asarray
-        sync the dispatch thread no longer pays)."""
-        with self._probe_lock:
-            pending, self._probe_pending = self._probe_pending, []
-        try:
-            for res in pending:
-                self._consume_probe(res)
-        except Exception:  # noqa: BLE001 - diagnostic only, never fail a fetch
-            log.exception("ubodt probe harvest failed; disabling")
-            self._probe_every = 0
-
     _host_copy_ok = True  # class-wide: disabled after the first failure
 
     @classmethod
@@ -1184,11 +1096,10 @@ class SegmentMatcher:
             from ..ops.viterbi import unpack_compact
 
             _, B, res, aux = handle
-            if self._probe_pending:
-                self._harvest_probe_stats()
-            edge, offset, breaks = unpack_compact(res)
-            if aux is not None:
-                aux = np.asarray(aux)[:B]
+            with obs_attrib.host_stage("fetch", n=B):
+                edge, offset, breaks = unpack_compact(res)
+                if aux is not None:
+                    aux = np.asarray(aux)[:B]
             return (edge[:B], offset[:B], breaks[:B]), aux
         return handle[1], None
 
@@ -1243,9 +1154,8 @@ class SegmentMatcher:
         # and each chunk below packs with one fancy-indexed scatter
         cols = None
         if self._host_pack:
-            t0h = _time.monotonic()
-            cols = columnar.extract_columns(traces)
-            obs_attrib.host_add("pack", _time.monotonic() - t0h)
+            with obs_attrib.host_stage("pack", n=len(traces)):
+                cols = columnar.extract_columns(traces)
         max_bucket = self.cfg.length_buckets[-1] if self.cfg.length_buckets else 256
         for i, tr in enumerate(traces):
             n = len(tr["trace"])
@@ -1292,15 +1202,13 @@ class SegmentMatcher:
                                       interp=interp_idx)
 
         for pkey, slabel, blen, idxs in chunks:
-            t0h = _time.monotonic()
-            px, py, tm, valid, times = self._fill_rows(traces, idxs, blen,
-                                                       cols=cols)
-            args = self._pad_batch_staged(px, py, tm, valid)
-            t1h = _time.monotonic()
-            handle = self._dispatch_batch(*args, pkey=pkey, slabel=slabel)
-            t2h = _time.monotonic()
-            obs_attrib.host_add("pack", t1h - t0h)
-            obs_attrib.host_add("dispatch", t2h - t1h)
+            with obs_attrib.host_stage("pack", n=len(idxs)):
+                px, py, tm, valid, times = self._fill_rows(
+                    traces, idxs, blen, cols=cols)
+                args = self._pad_batch_staged(px, py, tm, valid)
+            with obs_attrib.host_stage("dispatch", n=len(idxs)):
+                handle = self._dispatch_batch(*args, pkey=pkey,
+                                              slabel=slabel)
             pending.append((idxs, handle, times))
             if len(pending) >= PIPELINE_DEPTH:
                 drain_one()
@@ -1318,7 +1226,12 @@ class SegmentMatcher:
             long_handles.extend(self._dispatch_long(traces, lidx, pkey=pkey,
                                                     slabel=slabel, cols=cols))
 
-        def finish() -> List[dict]:
+        # the fetch and association host stages run on the finisher (and
+        # collector) threads: they carry the dispatching request's, or the
+        # batch's lead, trace id
+        span = obs_trace.current_span()
+
+        def _finish() -> List[dict]:
             # chaos seam: a wedged device step (the serve watchdog's prey)
             # is simulated here, inside the blocking finish the finisher
             # thread and the re-attach probe both run through
@@ -1354,11 +1267,12 @@ class SegmentMatcher:
                 # times, aux); None terminates, an exception object relays
                 # failure
                 try:
-                    for idxs_, handle_, times_ in work:
-                        res_, aux_ = self._collect_batch_aux(handle_)
-                        fetched.put((idxs_, res_, times_, aux_))
-                    for h in long_handles:
-                        fetched.put(self._fetch_long_aux(h))
+                    with obs_trace.bind(span):
+                        for idxs_, handle_, times_ in work:
+                            res_, aux_ = self._collect_batch_aux(handle_)
+                            fetched.put((idxs_, res_, times_, aux_))
+                        for h in long_handles:
+                            fetched.put(self._fetch_long_aux(h))
                     fetched.put(None)
                 except BaseException as e:  # noqa: BLE001 - relayed to caller
                     fetched.put(e)
@@ -1389,6 +1303,10 @@ class SegmentMatcher:
                 raise
             collector.join()
             return results  # type: ignore[return-value]
+
+        def finish() -> List[dict]:
+            with obs_trace.bind(span):
+                return _finish()
 
         return finish
 
@@ -1598,49 +1516,49 @@ class SegmentMatcher:
         trace indices whose association runs through the route-consistent
         interpolation engine (matching/sparse.py) instead of the batch
         walk — same record shape, speed-weighted boundary times."""
-        t0h = _time.monotonic()
-        B = len(idxs)
-        T = edge.shape[1]
-        abs_tm = np.zeros((B, T), np.float64)
-        n_pts = np.zeros(B, np.int32)
-        if isinstance(times, columnar.PackedTimes):
-            times.fill_abs(abs_tm, n_pts)  # vectorized scatter
-        else:
-            for row in range(B):
-                n_pts[row] = len(times[row])
-                abs_tm[row, : n_pts[row]] = times[row]
-        seg_lists = associate_segments_batch(
-            self.arrays, self.ubodt,
-            edge[:B], offset[:B], breaks[:B], abs_tm, n_pts,
-            queue_thresh_mps=self.cfg.queue_speed_threshold_kph / 3.6,
-            back_tol=2.0 * self.cfg.sigma_z + 5.0,
-        )
-        in_trace = np.arange(T)[None, :] < n_pts[:, None]
-        C_TRACES.inc(B)
-        C_POINTS.inc(int(n_pts.sum()))
-        C_BREAKS.inc(int(np.count_nonzero((breaks[:B] != 0) & in_trace)))
-        for row, i in enumerate(idxs):
-            results[i] = {"segments": seg_lists[row]}
-        if interp:
-            off32 = np.asarray(offset, np.float32)
+        with obs_attrib.host_stage("associate", n=len(idxs)):
+            B = len(idxs)
+            T = edge.shape[1]
+            abs_tm = np.zeros((B, T), np.float64)
+            n_pts = np.zeros(B, np.int32)
+            if isinstance(times, columnar.PackedTimes):
+                times.fill_abs(abs_tm, n_pts)  # vectorized scatter
+            else:
+                for row in range(B):
+                    n_pts[row] = len(times[row])
+                    abs_tm[row, : n_pts[row]] = times[row]
+            seg_lists = associate_segments_batch(
+                self.arrays, self.ubodt,
+                edge[:B], offset[:B], breaks[:B], abs_tm, n_pts,
+                queue_thresh_mps=self.cfg.queue_speed_threshold_kph / 3.6,
+                back_tol=2.0 * self.cfg.sigma_z + 5.0,
+            )
+            in_trace = np.arange(T)[None, :] < n_pts[:, None]
+            C_TRACES.inc(B)
+            C_POINTS.inc(int(n_pts.sum()))
+            C_BREAKS.inc(int(np.count_nonzero((breaks[:B] != 0) & in_trace)))
             for row, i in enumerate(idxs):
-                if i not in interp:
-                    continue
-                n = int(n_pts[row])
-                mps = [
-                    {"edge": int(edge[row, t]),
-                     "offset": float(off32[row, t]),
-                     "time": float(abs_tm[row, t]),
-                     "break": bool(breaks[row, t]),
-                     "shape_index": t}
-                    for t in range(n)
-                ]
-                results[i] = {"segments": associate_interpolated(
-                    self.arrays, self.ubodt, mps,
-                    queue_thresh_mps=self.cfg.queue_speed_threshold_kph / 3.6,
-                    back_tol=2.0 * self.cfg.sigma_z + 5.0,
-                )}
-        obs_attrib.host_add("collect", _time.monotonic() - t0h)
+                results[i] = {"segments": seg_lists[row]}
+            if interp:
+                off32 = np.asarray(offset, np.float32)
+                for row, i in enumerate(idxs):
+                    if i not in interp:
+                        continue
+                    n = int(n_pts[row])
+                    mps = [
+                        {"edge": int(edge[row, t]),
+                         "offset": float(off32[row, t]),
+                         "time": float(abs_tm[row, t]),
+                         "break": bool(breaks[row, t]),
+                         "shape_index": t}
+                        for t in range(n)
+                    ]
+                    results[i] = {"segments": associate_interpolated(
+                        self.arrays, self.ubodt, mps,
+                        queue_thresh_mps=(
+                            self.cfg.queue_speed_threshold_kph / 3.6),
+                        back_tol=2.0 * self.cfg.sigma_z + 5.0,
+                    )}
         if not self._quality_aux:
             return
         for row, i in enumerate(idxs):
@@ -1697,10 +1615,9 @@ class SegmentMatcher:
             group = order[g : g + cap]
             T_max = max(len(traces[i]["trace"]) for i in group)
             n_chunks = -(-T_max // W)
-            t0h = _time.monotonic()
-            px, py, tm, valid, times = self._fill_rows(
-                traces, group, n_chunks * W, cols=cols)
-            obs_attrib.host_add("pack", _time.monotonic() - t0h)
+            with obs_attrib.host_stage("pack", n=len(group)):
+                px, py, tm, valid, times = self._fill_rows(
+                    traces, group, n_chunks * W, cols=cols)
             px, py, tm, valid = self._pad_batch_staged(px, py, tm, valid)
             if self._mesh is not None and px.shape[0] % self._n_dp:
                 px, py, tm, valid = self._stage_rows(
@@ -1896,15 +1813,17 @@ class SegmentMatcher:
         from ..ops.viterbi import unpack_compact
 
         group, host_parts, dev_tail, times, aux_dev = handle
-        parts = list(host_parts)
-        parts.extend(unpack_compact(o) for o in dev_tail)
-        if len(parts) == 1:
-            edge, offset, breaks = parts[0]
-        else:
-            edge = np.concatenate([p[0] for p in parts], axis=1)
-            offset = np.concatenate([p[1] for p in parts], axis=1)
-            breaks = np.concatenate([p[2] for p in parts], axis=1)
-        aux = None if aux_dev is None else np.asarray(aux_dev)[: len(group)]
+        with obs_attrib.host_stage("fetch", n=len(group)):
+            parts = list(host_parts)
+            parts.extend(unpack_compact(o) for o in dev_tail)
+            if len(parts) == 1:
+                edge, offset, breaks = parts[0]
+            else:
+                edge = np.concatenate([p[0] for p in parts], axis=1)
+                offset = np.concatenate([p[1] for p in parts], axis=1)
+                breaks = np.concatenate([p[2] for p in parts], axis=1)
+            aux = (None if aux_dev is None
+                   else np.asarray(aux_dev)[: len(group)])
         return group, (edge, offset, breaks), times, aux
 
     # -- per-vehicle session steps (docs/performance.md "The session
@@ -2038,9 +1957,8 @@ class SegmentMatcher:
                      or [16])[-1])
         scols = None
         if self._host_pack:
-            t0h = _time.monotonic()
-            scols = columnar.extract_columns(items, key="points")
-            obs_attrib.host_add("pack", _time.monotonic() - t0h)
+            with obs_attrib.host_stage("pack", n=len(items)):
+                scols = columnar.extract_columns(items, key="points")
         groups: Dict[tuple, List[int]] = {}
         handles = []
         for i, it in enumerate(items):
@@ -2066,10 +1984,9 @@ class SegmentMatcher:
                 # transient device-program failure surfaces here and the
                 # session batcher's bisect-retry isolates it
                 faults.maybe_raise("ubodt_probe")
-                t0h = _time.monotonic()
-                px, py, tm, valid, ns = self._fill_session_rows(
-                    items, sub, W, cols=scols)
-                obs_attrib.host_add("pack", _time.monotonic() - t0h)
+                with obs_attrib.host_stage("pack", n=len(sub)):
+                    px, py, tm, valid, ns = self._fill_session_rows(
+                        items, sub, W, cols=scols)
                 if self.backend != "jax":
                     cpu = self._cpu if not pkey else self._cpu_for(pkey)
                     res = cpu.run_batch(px, py, tm, valid)

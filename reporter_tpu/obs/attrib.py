@@ -43,17 +43,20 @@ usable in processes that never touch the device.
 from __future__ import annotations
 
 import collections
+import contextlib
 import glob
 import gzip
 import json
 import logging
 import os
 import re
+import sys
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from . import metrics
+from . import trace as obs_trace
 
 log = logging.getLogger(__name__)
 
@@ -94,8 +97,6 @@ def stage(name: str):
     numerics are identical either way (tests/test_attrib.py pins the
     outputs bit-identical)."""
     if not scopes_enabled():
-        import contextlib
-
         return contextlib.nullcontext()
     import jax
 
@@ -466,50 +467,107 @@ def roofline_block(n_traces: int, T: int, k: int, secs: float, *,
 
 # -- host-stage attribution (docs/performance.md "The columnar host data
 # plane"): wall seconds the GIL-bound host spends per pipeline stage,
-# accumulated at the stage boundaries the serving path already crosses
-# (service request decode -> matcher pack -> device dispatch -> result
-# collect/associate -> service response encode).  The device side has
-# named_scope attribution; this is its host mirror, and the bench
-# host_frac (host / (host + kernel)) is what perf_gate judges.
-HOST_STAGES = ("parse", "pack", "dispatch", "collect", "serialize")
+# counted where each stage runs (service request decode -> matcher pack
+# -> device dispatch -> result fetch -> host association -> report() ->
+# tile rows -> service response encode; the batch pipeline adds the shard
+# read).  The device side has named_scope attribution; this is its host
+# mirror, on the profiler's clock too: every stage is also a
+# ``reporter.<stage>`` host span in a jax.profiler capture, so an idle
+# gap in the device trace is named by the host work under it.
+HOST_STAGES = ("parse", "pack", "dispatch", "fetch", "associate", "report",
+               "tile", "read", "serialize")
+HOST_SPAN_PREFIX = "reporter."
 C_HOST_STAGE = metrics.counter(
     "reporter_host_stage_seconds_total",
     "Wall seconds of host pipeline work by stage (parse = request-body "
     "decode, pack = batch packing into padded device arrays, dispatch = "
-    "device program enqueue, collect = result fetch + host association, "
-    "serialize = response encode; GET /debug/attrib reports the split)",
+    "device program enqueue, fetch = blocking wait for a batch's device "
+    "result and its copy to the host, associate = host segment "
+    "association of fetched results, report = report() rendering, tile = "
+    "batch tile-row fan-out and file writes, read = batch shard-file "
+    "parse and window split, serialize = response encode; GET "
+    "/debug/attrib reports the split)",
     ("stage",))
-_HOST_S = {s: 0.0 for s in HOST_STAGES}
-_HOST_LOCK = threading.Lock()
+# one child per stage, made once: the series exist from the start and a
+# stage's increment skips the label lookup
+_HOST_STAGE_CHILD = {s: C_HOST_STAGE.labels(s) for s in HOST_STAGES}
 
 
-def host_add(stage: str, secs: float) -> None:
-    """Accrue ``secs`` of host work to ``stage``.  Called per batch/
-    request (never per point), so the lock is uncontended noise."""
-    if secs <= 0:
-        return
-    with _HOST_LOCK:
-        _HOST_S[stage] = _HOST_S.get(stage, 0.0) + secs
-    C_HOST_STAGE.labels(stage).inc(secs)
+def _host_annotation(stage: str, span, meta: dict):
+    """The ``reporter.<stage>`` TraceAnnotation, or None where no caller
+    has imported jax."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    if profiler is None:
+        return None
+    if meta.get("trace_id") is None:
+        tid = (span.trace_id if span is not None
+               else obs_trace.current_trace_id())
+        if tid is None:
+            meta.pop("trace_id", None)
+        else:
+            meta["trace_id"] = tid
+    return profiler.TraceAnnotation(HOST_SPAN_PREFIX + stage, **meta)
+
+
+class host_stage:
+    """``with host_stage("fetch", span, n=len(batch)):`` — time one host
+    stage of one request or micro-batch (never one point or window).
+
+    On exit the block's wall seconds go to
+    ``reporter_host_stage_seconds_total{stage}``.  While it is open, a
+    ``jax.profiler.TraceAnnotation("reporter.<stage>", trace_id=..., **meta)``
+    marks it on the profiler's host timeline, so a capture shows which host
+    stage sits under each device idle gap.  ``trace_id`` is ``meta``'s, else
+    ``span``'s, else the bound span's (obs/trace.py); a batch passes its
+    lead span and ``n``, its trace count.  jax is used only when a caller
+    has already imported it, so obs stays importable without jax; with no
+    capture recording, the annotation is one TraceMe enter/exit."""
+
+    __slots__ = ("_child", "_ann", "_t0")
+
+    def __init__(self, stage: str, span=None, **meta):
+        self._child = _HOST_STAGE_CHILD[stage]
+        self._ann = _host_annotation(stage, span, meta)
+
+    def __enter__(self) -> "host_stage":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._child.inc(time.perf_counter() - self._t0)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+
+def host_span(name: str, span=None, **meta):
+    """A ``reporter.<name>`` profiler span with no counter, for waiting
+    that another metric already counts (the micro-batcher's fill window)."""
+    return _host_annotation(name, span, meta) or contextlib.nullcontext()
 
 
 def host_snapshot() -> Dict[str, float]:
-    with _HOST_LOCK:
-        return dict(_HOST_S)
+    """Cumulative seconds per host stage, read from the counter."""
+    return {lv[0]: child.value for lv, child in C_HOST_STAGE._items()}
 
 
 def host_summary(since: Optional[Dict[str, float]] = None) -> dict:
     """The host-stage split: cumulative (or since a snapshot) seconds per
-    stage plus each stage's share of the host total."""
+    stage plus each stage's share of the host total.  ``fetch`` is a wait
+    on the device, not host work, so the total and the split leave it
+    out."""
     now = host_snapshot()
     if since:
         now = {k: max(0.0, v - since.get(k, 0.0)) for k, v in now.items()}
-    total = sum(now.values())
+    work = {k: v for k, v in now.items() if k != "fetch"}
+    total = sum(work.values())
     return {
         "stages_s": {k: round(v, 6) for k, v in now.items()},
         "total_s": round(total, 6),
         "split": {k: (round(v / total, 4) if total > 0 else 0.0)
-                  for k, v in now.items()},
+                  for k, v in work.items()},
     }
 
 

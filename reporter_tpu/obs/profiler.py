@@ -9,6 +9,11 @@ request (either endpoint, any kind) gets ``ProfilerBusy`` carrying the
 in-flight capture's trace_id (HTTP 409) instead of corrupting the first.
 jax is imported lazily: the obs package stays importable (and the metrics
 registry usable) in processes that never touch the device.
+
+Captures record the device planes and the host's TraceMe events (the
+``reporter.<stage>`` host spans among them) without the Python function
+tracer, which hooks every Python call of the host path it would measure;
+``python=True`` turns it back on.
 """
 
 from __future__ import annotations
@@ -44,12 +49,14 @@ def inflight() -> "dict | None":
 
 @contextlib.contextmanager
 def session(kind: str, trace_id: "str | None" = None,
-            out_dir: "str | None" = None, seconds: "float | None" = None):
+            out_dir: "str | None" = None, seconds: "float | None" = None,
+            python: bool = False):
     """Single-flight jax.profiler window: acquires the process-global
     capture lock (non-blocking; raises ProfilerBusy with the in-flight
     capture's metadata), starts the trace, yields the trace dir, and
     stops the trace on exit.  ``trace_id`` defaults to the caller's bound
-    span so a 409 can name the request that owns the capture."""
+    span so a 409 can name the request that owns the capture.
+    ``python`` turns on the Python function tracer (off by default)."""
     global _inflight
     if trace_id is None:
         from . import trace as obs_trace
@@ -65,7 +72,9 @@ def session(kind: str, trace_id: "str | None" = None,
                      "started_unix": round(time.time(), 3),
                      "seconds": seconds}
         d = out_dir or tempfile.mkdtemp(prefix="reporter_jax_trace_")
-        jax.profiler.start_trace(d)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 1 if python else 0
+        jax.profiler.start_trace(d, profiler_options=opts)
         try:
             yield d
         finally:
@@ -75,11 +84,14 @@ def session(kind: str, trace_id: "str | None" = None,
         _capture_lock.release()
 
 
-def capture(seconds: float, out_dir: str = None) -> "tuple[str, float]":
+def capture(seconds: float, out_dir: str = None,
+            python: bool = False) -> "tuple[str, float]":
     """Record a jax profiler trace for ~``seconds`` (clamped to
-    [MIN_SECONDS, MAX_SECONDS]).  Returns (trace_dir, seconds_recorded);
-    the dir holds a TensorBoard-loadable trace."""
+    [MIN_SECONDS, MAX_SECONDS]), with the Python function tracer only when
+    ``python``.  Returns (trace_dir, seconds_recorded); the dir holds a
+    TensorBoard-loadable trace."""
     seconds = min(max(float(seconds), MIN_SECONDS), MAX_SECONDS)
-    with session("profile", out_dir=out_dir, seconds=seconds) as d:
+    with session("profile", out_dir=out_dir, seconds=seconds,
+                 python=python) as d:
         time.sleep(seconds)
     return d, seconds

@@ -48,7 +48,7 @@ def ubodt_probe_stats(dg, du, xin, p: MatchParams, k: int,
       [4] distinct     distinct (src, dst) node pairs among [0] across the
                        WHOLE batch — pairs/distinct is the in-batch probe
                        redundancy the dedup path exploits
-                       (reporter_probe_dedup_ratio, bench ``probe_dedup``;
+                       (bench ``probe_dedup``;
                        docs/performance.md memory-system section)
     """
     px, py, tm, valid = unpack_inputs(xin)

@@ -50,8 +50,8 @@ from ..tiles.ubodt import (
 
 # compacted-unique capacity = pair count // ratio: the static budget the
 # deduped gather runs at.  2 is conservative — realistic fleet batches
-# measure 4-8x redundant (the reporter_probe_dedup_ratio gauge / bench
-# probe_dedup field carry the live number) — so the capacity practically
+# measure 4-8x redundant (the bench probe_dedup field carries the
+# number) — so the capacity practically
 # never overflows while still halving the executed row count even before
 # the wide32 halving.
 _DEDUP_CAP_RATIO = 2
@@ -216,7 +216,7 @@ def count_distinct_pairs(src: jnp.ndarray, dst: jnp.ndarray,
                          valid: jnp.ndarray) -> jnp.ndarray:
     """Scalar i32: distinct (src, dst) pairs among positions where ``valid``
     — the numerator of the probe-dedup redundancy diagnostics
-    (ops/diagnostics.ubodt_probe_stats -> reporter_probe_dedup_ratio)."""
+    (ops/diagnostics.ubodt_probe_stats)."""
     s = jnp.where(valid, src, -1).reshape(-1).astype(jnp.int32)
     d = jnp.where(valid, dst, -1).reshape(-1).astype(jnp.int32)
     sk, dk = jax.lax.sort((s, d), num_keys=2)

@@ -53,6 +53,7 @@ from ..obs import metrics as obs
 from ..obs import quality as obs_quality
 from ..obs import slo as obs_slo
 from ..obs import trace as obs_trace
+from ..obs.quantile import SLO_BUCKETS_S
 from ..obs.trace import Span
 from ..report import report as report_fn
 from ..tiles.network import RoadNetwork, grid_city
@@ -109,7 +110,8 @@ def _resolve_num(env_name: str, param, default: float) -> float:
 # service — aggregate throughput alone cannot show a queue-wait regression
 M_QUEUE_WAIT = obs.histogram(
     "reporter_microbatch_queue_wait_seconds",
-    "Per-trace wait from submit to micro-batch formation")
+    "Per-trace wait from submit to micro-batch formation",
+    buckets=SLO_BUCKETS_S)
 M_BATCH_FILL = obs.histogram(
     "reporter_microbatch_batch_fill",
     "Traces per dispatched device micro-batch",
@@ -492,16 +494,19 @@ class MicroBatcher:
             entry = self._q.get()
             batch = [entry]
             # opportunistically fill the batch within one absolute window so
-            # the first request's extra latency is bounded by max_wait
+            # the first request's extra latency is bounded by max_wait; the
+            # profiler span names this by-design wait on the host timeline
+            # (the queue-wait histogram already counts it)
             deadline = _time.monotonic() + self.max_wait
-            while len(batch) < self.max_batch:
-                remaining = deadline - _time.monotonic()
-                if remaining <= 0:
-                    break
-                try:
-                    batch.append(self._q.get(timeout=remaining))
-                except queue.Empty:
-                    break
+            with obs_attrib.host_span("batch_fill", entry[3]):
+                while len(batch) < self.max_batch:
+                    remaining = deadline - _time.monotonic()
+                    if remaining <= 0:
+                        break
+                    try:
+                        batch.append(self._q.get(timeout=remaining))
+                    except queue.Empty:
+                        break
             now = _time.monotonic()
             # deadline scrub BEFORE dispatch: an entry whose budget died in
             # the queue answers 504 now and never wastes a device slot (its
@@ -1372,10 +1377,11 @@ class ReporterService:
                         "match_options": trace.get("match_options") or {}}
                 quality = self._note_quality(render_trace, match, span)
                 t_rep = _time.monotonic()
-                data = report_fn(match, render_trace, self.threshold_sec,
-                                 rl, tl,
-                                 mode=(trace.get("match_options") or {})
-                                 .get("mode", "auto"))
+                with obs_attrib.host_stage("report", span):
+                    data = report_fn(match, render_trace, self.threshold_sec,
+                                     rl, tl,
+                                     mode=(trace.get("match_options") or {})
+                                     .get("mode", "auto"))
             span.mark("report_fn_s", _time.monotonic() - t_rep)
             span.finish()
             if st is not None:
@@ -1705,11 +1711,13 @@ class ReporterService:
                 for m_, (t_, _rl, _tl) in zip(matches, validated):
                     self._note_quality(t_, m_, span)
                 t0 = _time.monotonic()
-                results = [
-                    report_fn(m, t, self.threshold_sec, rl, tl,
-                              mode=t.get("match_options", {}).get("mode", "auto"))
-                    for m, (t, rl, tl) in zip(matches, validated)
-                ]
+                with obs_attrib.host_stage("report", span, n=len(validated)):
+                    results = [
+                        report_fn(m, t, self.threshold_sec, rl, tl,
+                                  mode=t.get("match_options", {})
+                                  .get("mode", "auto"))
+                        for m, (t, rl, tl) in zip(matches, validated)
+                    ]
                 span.mark("report_fn_s", _time.monotonic() - t0)
             degraded = bool(span.meta.get("degraded"))
             self._terminal("trace_attributes_batch", 200, span,
@@ -1904,19 +1912,21 @@ class ReporterService:
         return 200, out
 
     def handle_profile(self, query: dict) -> Tuple[int, dict]:
-        """GET /debug/profile?seconds=N — record a jax.profiler trace to a
-        temp dir and return its path (TensorBoard-loadable)."""
+        """GET /debug/profile?seconds=N[&python=1] — record a jax.profiler
+        trace to a temp dir and return its path (TensorBoard-loadable).
+        The Python function tracer is off unless ``python=1``."""
         from ..obs import profiler
 
         try:
             seconds = float(query.get("seconds", ["2"])[0])
         except (TypeError, ValueError):
             return 400, {"error": "seconds must be a number"}
+        python = query.get("python", ["0"])[0] not in ("", "0", "false")
         m = self.matcher
         if m is not None and m.backend != "jax":
             return 501, {"error": "profiling needs the jax backend (got %r)" % m.backend}
         try:
-            trace_dir, recorded = profiler.capture(seconds)
+            trace_dir, recorded = profiler.capture(seconds, python=python)
         except profiler.ProfilerBusy as e:
             # single-flight: the in-flight capture's trace_id rides the 409
             # so the caller can find (or wait out) the owner
@@ -1924,7 +1934,8 @@ class ReporterService:
         except Exception as e:  # noqa: BLE001 - surfaced to the caller
             log.exception("profiler capture failed")
             return 500, {"error": str(e)}
-        return 200, {"trace_dir": trace_dir, "seconds": recorded}
+        return 200, {"trace_dir": trace_dir, "seconds": recorded,
+                     "python": python}
 
     def handle_attrib(self, query: dict) -> Tuple[int, dict]:
         """GET /debug/attrib — the last parsed named-stage attribution
@@ -1997,27 +2008,29 @@ class ReporterService:
             # would hang on any idle persistent client
             timeout = 30
 
-            def _answer(self, code: int, payload: dict):
-                t0s = _time.monotonic()
-                body = None
-                ctype = "application/json;charset=utf-8"
+            def _encode(self, code: int, payload: dict):
+                """(body bytes, content type) of one answer."""
                 if code == 200 and getattr(self, "_accept_wire", False):
                     # the client negotiated the binary columnar wire
                     # (Accept: application/x-reporter-columnar) — only
                     # 200 report payloads encode; every error shape
                     # stays JSON so clients keep one error parser
                     try:
-                        body = wire.encode_response(
-                            payload, single=self._wire_single)
-                        ctype = wire.CONTENT_TYPE
+                        return (wire.encode_response(
+                            payload, single=self._wire_single),
+                            wire.CONTENT_TYPE)
                     except Exception:  # noqa: BLE001 - fall back to JSON
-                        body = None
-                if body is None:
-                    body = json.dumps(
-                        payload, separators=(",", ":")).encode("utf-8")
+                        pass
+                return (json.dumps(payload, separators=(",", ":"))
+                        .encode("utf-8"), "application/json;charset=utf-8")
+
+            def _answer(self, code: int, payload: dict):
                 if getattr(self, "_timed_route", False):
-                    obs_attrib.host_add(
-                        "serialize", _time.monotonic() - t0s)
+                    with obs_attrib.host_stage("serialize",
+                                               trace_id=self._trace_id):
+                        body, ctype = self._encode(code, payload)
+                else:
+                    body, ctype = self._encode(code, payload)
                 self.send_response(code)
                 self.send_header("Access-Control-Allow-Origin", "*")
                 self.send_header("Content-Type", ctype)
@@ -2187,31 +2200,31 @@ class ReporterService:
                             return self._answer(
                                 400, {"error": "invalid Content-Length"})
                         raw = self.rfile.read(n)
-                        # body decode = the "parse" host stage: gzip
-                        # inflate (bounded), then the negotiated wire —
-                        # binary columnar frames by Content-Type, JSON
-                        # otherwise (docs/http-api.md "Wire formats")
-                        t0p = _time.monotonic()
                         enc = (self.headers.get("Content-Encoding")
                                or "").strip().lower()
-                        if enc == "gzip":
-                            raw = _gunzip(raw)
-                        elif enc not in ("", "identity"):
+                        if enc not in ("", "identity", "gzip"):
                             return self._answer(
                                 415, {"error": "unsupported "
                                       "Content-Encoding %r (gzip or "
                                       "identity)" % enc})
-                        if wire.is_wire(
-                                self.headers.get("Content-Type")):
-                            if not service.wire_enabled:
-                                return self._answer(
-                                    415, {"error": "binary wire disabled "
-                                          "(REPORTER_WIRE=0)"})
-                            payload = wire.decode_request(raw)
-                        else:
-                            payload = json.loads(raw.decode("utf-8"))
-                        obs_attrib.host_add(
-                            "parse", _time.monotonic() - t0p)
+                        binary = wire.is_wire(
+                            self.headers.get("Content-Type"))
+                        if binary and not service.wire_enabled:
+                            return self._answer(
+                                415, {"error": "binary wire disabled "
+                                      "(REPORTER_WIRE=0)"})
+                        # body decode = the "parse" host stage: gzip
+                        # inflate (bounded), then the negotiated wire —
+                        # binary columnar frames by Content-Type, JSON
+                        # otherwise (docs/http-api.md "Wire formats")
+                        with obs_attrib.host_stage(
+                                "parse", trace_id=self._trace_id):
+                            if enc == "gzip":
+                                raw = _gunzip(raw)
+                            if binary:
+                                payload = wire.decode_request(raw)
+                            else:
+                                payload = json.loads(raw.decode("utf-8"))
                     else:
                         if "json" not in query:
                             return self._answer(400, {"error": "No json provided"})

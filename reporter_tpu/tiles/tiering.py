@@ -260,9 +260,9 @@ class TieredTable:
         # handles (touching the runtime from callback context can
         # deadlock against a concurrent device fetch on the CPU client —
         # observed with tools/tiering_probe.py); this drain thread
-        # converts and accumulates afterwards, the same dispatch-side /
-        # collect-side split matcher._record_probe_stats uses.  Bounded:
-        # under a stats backlog old samples drop, never dispatches.
+        # converts and accumulates afterwards, off the dispatch thread.
+        # Bounded: under a stats backlog old samples drop, never
+        # dispatches.
         self._stats_q: "deque" = deque(maxlen=256)
         self._stats_wake = threading.Event()
         self._stats_thread = threading.Thread(

@@ -270,29 +270,3 @@ def test_stage_rows_reuses_pinned_buffers(engine):
     assert (out2[1][2:] == 0).all() and (out2[1][:2] == 5.0).all()
     # distinct slots never share a buffer even at identical shape/dtype
     assert id(out2[0]) != id(out2[1])
-
-
-def test_probe_stats_deferred_off_dispatch(engine, monkeypatch):
-    """The sampled UBODT probe is dispatched on the hot thread but its
-    np.asarray sync happens on the collect side: after a dispatch tick the
-    probe sits in _probe_pending; the collect drains it into the outcome
-    counters."""
-    monkeypatch.setenv("REPORTER_OBS_PROBE_EVERY", "1")
-    arrays, ubodt = engine
-    matcher = SegmentMatcher(
-        arrays=arrays, ubodt=ubodt, config=MatcherConfig(**CFG))
-    assert matcher._probe_every == 1
-
-    def _pairs_total():
-        snap = obs.REGISTRY.snapshot().get(
-            "reporter_ubodt_probe_total", {"samples": []})
-        return sum(v for lv, v in snap["samples"] if lv == ["pairs"])
-
-    before = _pairs_total()
-    t = _trace(arrays, 10)
-    px, py, tm, valid, _times = matcher._fill_rows([t], [0], 16)
-    handle = matcher._dispatch_batch(px, py, tm, valid)
-    assert len(matcher._probe_pending) == 1, "probe sync ran on dispatch"
-    matcher._collect_batch(handle)
-    assert not matcher._probe_pending
-    assert _pairs_total() > before
