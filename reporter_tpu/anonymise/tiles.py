@@ -155,6 +155,48 @@ def observations_for_report(
         yield TimeQuantisedTile(b * quantisation, tile_id), obs
 
 
+def tile_rows(
+    pairs: Iterable[Tuple[dict, dict]],
+    quantisation: int,
+    source: str,
+    vehicle_type: str,
+    tiles: Dict[str, List[str]],
+) -> List[Tuple[dict, int]]:
+    """Append the CSV row of every usable report of a micro-batch's
+    (request, report()) pairs to each of its tile paths in ``tiles``, in
+    pair order: the rows ``observations_for_report`` gives, as
+    ``SegmentObservation.csv_row()`` writes them ("%s" is ``str``), in one
+    flat pass.  A report spanning more buckets than its request's points
+    (``max_buckets``) is left out; returns (request, max_buckets) for each."""
+    q = quantisation
+    tail = ",%s,%s\n" % (source, vehicle_type)
+    spilled = []
+    for request, rep in pairs:
+        points = request["trace"]
+        max_buckets = (points[-1]["time"] - points[0]["time"]) // q + 1
+        for r in rep["datastore"]["reports"]:
+            if not usable_report(r):
+                continue
+            t0, t1, sid = r["t0"], r["t1"], r["id"]
+            start = int(math.floor(t0))
+            end = int(math.ceil(t1))
+            lo, hi = start // q, end // q
+            if hi - lo > max_buckets:
+                spilled.append((request, max_buckets))
+                continue
+            row = "%s,%s,%s,1,%s,%s,%s,%s" % (
+                sid, r.get("next_id", INVALID_SEGMENT_ID),
+                int(math.floor((t1 - t0) + 0.5)), r["length"],
+                r["queue_length"], start, end) + tail
+            # {bucket start}_{end}/{level}/{tile index}, as
+            # TimeQuantisedTile.path has it
+            where = "/%d/%d" % (get_tile_level(sid), get_tile_index(sid))
+            for b in range(lo, hi + 1):
+                tiles.setdefault("%d_%d%s" % (b * q, b * q + q - 1, where),
+                                 []).append(row)
+    return spilled
+
+
 def privacy_cull(observations: List[SegmentObservation], privacy: int) -> List[SegmentObservation]:
     """Drop (segment_id, next_segment_id) groups observed fewer than
     ``privacy`` times.  Sorts first, like both reference implementations."""

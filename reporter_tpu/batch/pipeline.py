@@ -7,14 +7,21 @@ Phase 1  get_traces   -- crawl an archive (local dir, or S3 when boto3 is
                          vehicle's points land in one file.  Fans out over
                          ``concurrency`` processes on hash-partitioned key
                          lists (split(), simple_reporter.py:70-79).
-Phase 2  make_matches -- per shard file: group by uuid, sort by time, split
-                         traces at inactivity gaps (>120 s default,
-                         simple_reporter.py:149-163), then match ALL windows
-                         of the file in pooled [B, T] device micro-batches,
-                         run report(), keep usable segments, and fan them
-                         across quantised time buckets into tile files
-                         (simple_reporter.py:176-196).  One process drives
-                         the device; batching replaces process fan-out.
+Phase 2  make_matches -- per shard file, in columns (_read_requests): the
+                         native parser's columns grouped by uuid (one sort,
+                         each distinct uuid decoded once), each vehicle's
+                         points stably sorted by time, split at inactivity
+                         gaps (>120 s default, simple_reporter.py:149-163)
+                         with one diff; the windows' point dicts built in
+                         bulk, each with its f64 ``_columns`` attached so
+                         the matcher packs without walking them.  ALL
+                         windows of the file are matched in pooled [B, T]
+                         device micro-batches, report() runs, and each
+                         micro-batch's usable segments fan across quantised
+                         time buckets in one flat pass of CSV rows
+                         (anonymise.tiles.tile_rows; simple_reporter.py:
+                         176-196) into tile files.  One process drives the
+                         device; batching replaces process fan-out.
 Phase 3  report_tiles -- sort each tile file, cull segment pairs seen fewer
                          than ``privacy`` times, upload CSV with header
                          (simple_reporter.py:211-254).
@@ -42,7 +49,9 @@ import re
 import tempfile
 import time
 import uuid as uuidlib
-from typing import Callable, Iterable, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
+
+import numpy as np
 
 from ..anonymise.storage import make_store
 from ..utils import journal
@@ -53,11 +62,10 @@ from ..obs import trace as obs_trace
 from ..anonymise.tiles import (
     CSV_HEADER,
     SegmentObservation,
-    observations_for_report,
     privacy_cull,
-    usable_report,
+    tile_rows,
 )
-from ..native import parse_shard_bytes
+from ..native import parse_shard_columns
 from ..report.reporter import report as report_fn
 
 log = logging.getLogger("reporter_tpu.batch")
@@ -79,6 +87,10 @@ C_ROWS_SKIPPED = obs.counter(
 C_WINDOWS = obs.counter(
     "reporter_batch_windows_matched_total",
     "Trace windows matched and reported in phase 2")
+C_COLUMNAR = obs.counter(
+    "reporter_batch_columnar_windows_total",
+    "Trace windows sent to the matcher with their point columns attached "
+    "in phase 2")
 C_REPORT_FAIL = obs.counter(
     "reporter_batch_report_failures_total",
     "Trace windows whose match or report failed in phase 2")
@@ -324,15 +336,15 @@ def get_traces(
 
 
 def _iter_shard_chunks(file_name: str, chunk_bytes: int = 1 << 26):
-    """Yield (uuids, time, lat, lon, acc, line_count) per newline-aligned
-    chunk of the shard file."""
+    """Yield (codes, uuids, time, lat, lon, acc, line_count) per
+    newline-aligned chunk of the shard file (parse_shard_columns' columns)."""
     with open(file_name, "rb") as f:
         carry = b""
         while True:
             block = f.read(chunk_bytes)
             if not block:
                 if carry.strip():
-                    parsed = parse_shard_bytes(carry)
+                    parsed = parse_shard_columns(carry)
                     yield (*parsed, carry.count(b"\n") + (0 if carry.endswith(b"\n") else 1))
                 return
             data = carry + block
@@ -341,22 +353,55 @@ def _iter_shard_chunks(file_name: str, chunk_bytes: int = 1 << 26):
                 carry = data
                 continue
             chunk, carry = data[: cut + 1], data[cut + 1 :]
-            parsed = parse_shard_bytes(chunk)
+            parsed = parse_shard_columns(chunk)
             yield (*parsed, chunk.count(b"\n"))
 
 
-def _windows(points: List[dict], inactivity: float) -> Iterable[List[dict]]:
-    """Split a sorted point list at inactivity gaps; drop <2-point windows
-    (simple_reporter.py:149-163)."""
-    starts = [
-        i
-        for i, p in enumerate(points)
-        if i == 0 or p["time"] - points[i - 1]["time"] > inactivity
-    ]
-    for idx, i in enumerate(starts):
-        j = starts[idx + 1] if idx + 1 < len(starts) else len(points)
-        if j - i >= 2:
-            yield points[i:j]
+def _read_requests(file_name: str, inactivity: float, mode: str,
+                   chunk_bytes: int = 1 << 26) -> List[dict]:
+    """One shard file -> its match requests, in columns.
+
+    Rows are grouped by uuid (vehicles in order of first appearance), each
+    vehicle's points stably sorted by time, so equal times keep file order
+    and competing phase-1 appends are repaired (simple_reporter.py:145-146),
+    and split at gaps > ``inactivity``; windows of < 2 points are dropped
+    (simple_reporter.py:149-163).  Each request carries its point dicts and
+    the f64 ``"_columns"`` the matcher packs from without walking them.
+    Torn rows are skipped (concurrent phase-1 appends can tear a line
+    mid-write) and counted."""
+    ids: dict = {}          # uuid -> vehicle number, in order of first appearance
+    parts = []
+    skipped = 0
+    # bounded chunks, so a multi-GB archive doesn't spike memory
+    for codes, uuids, tms, lats, lons, accs, lines in \
+            _iter_shard_chunks(file_name, chunk_bytes):
+        skipped += lines - len(tms)
+        vid = np.array([ids.setdefault(u, len(ids)) for u in uuids], np.int64)
+        parts.append((vid[codes], tms, lats, lons, accs))
+    if skipped:
+        C_ROWS_SKIPPED.inc(skipped)
+        log.warning("skipped %d malformed row(s) in %s", skipped, file_name)
+    if not parts:
+        return []
+    veh, tm, lat, lon, acc = (np.concatenate(c) for c in zip(*parts))
+    order = np.lexsort((tm, veh))       # stable, like list.sort
+    veh, tm, lat, lon, acc = (a[order] for a in (veh, tm, lat, lon, acc))
+    # an int gap exceeds a float limit exactly when it exceeds its floor
+    limit = math.floor(inactivity) if math.isfinite(inactivity) else inactivity
+    cut = np.flatnonzero((veh[1:] != veh[:-1]) | (np.diff(tm) > limit)) + 1
+    starts = np.concatenate(([0], cut))
+    ends = np.concatenate((cut, [len(tm)]))
+    keep = ends - starts >= 2
+    starts, ends = starts[keep].tolist(), ends[keep].tolist()
+    points = [{"lat": a, "lon": b, "time": t, "accuracy": c}
+              for a, b, t, c in zip(lat.tolist(), lon.tolist(), tm.tolist(),
+                                    acc.tolist())]
+    tf = tm.astype(np.float64)
+    names = list(ids)
+    return [{"uuid": names[v], "trace": points[i:j],
+             "match_options": {"mode": mode},
+             "_columns": {"lat": lat[i:j], "lon": lon[i:j], "time": tf[i:j]}}
+            for v, i, j in zip(veh[starts].tolist(), starts, ends)]
 
 
 def make_matches(
@@ -387,37 +432,8 @@ def make_matches(
     transition_levels = set(transition_levels)
 
     for file_name in file_names:
-        # the native parser skips torn rows (concurrent phase-1 appends can
-        # tear a line mid-write); so does its Python fallback.  Shards are
-        # read in bounded chunks so a multi-GB archive doesn't spike memory.
         with obs_attrib.host_stage("read"):
-            traces: dict = {}
-            skipped = 0
-            for uuids, tms, lats, lons, accs, chunk_lines in \
-                    _iter_shard_chunks(file_name):
-                skipped += chunk_lines - len(uuids)
-                for i in range(len(uuids)):
-                    traces.setdefault(uuids[i], []).append(
-                        {
-                            "lat": float(lats[i]),
-                            "lon": float(lons[i]),
-                            "time": int(tms[i]),
-                            "accuracy": int(accs[i]),
-                        }
-                    )
-            if skipped:
-                C_ROWS_SKIPPED.inc(skipped)
-                log.warning("skipped %d malformed row(s) in %s", skipped,
-                            file_name)
-
-            # build every match request up front; competing phase-1
-            # appends are repaired by the sort (simple_reporter.py:145-146)
-            requests = []
-            for uuid, points in traces.items():
-                points.sort(key=lambda v: v["time"])
-                for window in _windows(points, inactivity):
-                    requests.append({"uuid": uuid, "trace": window,
-                                     "match_options": {"mode": mode}})
+            requests = _read_requests(file_name, inactivity, mode)
 
         tiles: dict = {}
         matched = 0
@@ -430,6 +446,7 @@ def make_matches(
             span = obs_trace.Span("batch_microbatch")
             span.meta["file"] = os.path.basename(file_name)
             span.meta["n_traces"] = len(chunk)
+            C_COLUMNAR.inc(sum("_columns" in r for r in chunk))
             try:
                 with obs_trace.bind(span):
                     t0 = time.monotonic()
@@ -463,11 +480,8 @@ def make_matches(
                         continue
                     reports.append((request, rep))
             with obs_attrib.host_stage("tile", span, n=len(reports)):
-                for request, rep in reports:
-                    _bucket_reports(
-                        rep, request, quantisation, source, mode, tiles,
-                        file_name
-                    )
+                _bucket_reports(reports, quantisation, source, mode, tiles,
+                                file_name)
             matched += len(reports)
             C_WINDOWS.inc(len(reports))
             span.mark("report_fn_s", time.monotonic() - t0)
@@ -488,26 +502,15 @@ def make_matches(
     return dest_dir
 
 
-def _bucket_reports(rep, request, quantisation, source, mode, tiles, file_name):
-    """Fan one report()'s usable segments across quantised time buckets
-    (simple_reporter.py:176-196), via the shared tiling helpers so the batch
-    and streaming paths can't drift."""
-    points = request["trace"]
-    max_buckets = (points[-1]["time"] - points[0]["time"]) // quantisation + 1
-    for r in rep["datastore"]["reports"]:
-        if not usable_report(r):
-            continue
-        emitted = False
-        for tile, obs in observations_for_report(
-            r, quantisation, source, vehicle_type=mode.upper(), max_buckets=max_buckets
-        ):
-            tiles.setdefault(tile.path(quantisation), []).append(obs.csv_row() + "\n")
-            emitted = True
-        if not emitted:
-            log.error(
-                "segment spans more than %d buckets for uuid %s in %s",
-                max_buckets, request["uuid"], file_name,
-            )
+def _bucket_reports(reports, quantisation, source, mode, tiles, file_name):
+    """Fan a micro-batch's usable report()s across quantised time buckets
+    (simple_reporter.py:176-196) in one flat pass of CSV rows."""
+    for request, max_buckets in tile_rows(reports, quantisation, source,
+                                          mode.upper(), tiles):
+        log.error(
+            "segment spans more than %d buckets for uuid %s in %s",
+            max_buckets, request["uuid"], file_name,
+        )
 
 
 # -- phase 3: anonymise + upload ------------------------------------------

@@ -188,9 +188,12 @@ def get_lib(force_rebuild: bool = False) -> Optional[ctypes.CDLL]:
         return _lib
 
 
-def parse_shard_bytes(data: bytes, lib=None):
-    """Parse shard rows 'uuid,epoch,lat,lon,acc' -> (uuids, time, lat, lon,
-    acc).  Native when available, numpy/python otherwise."""
+def parse_shard_columns(data: bytes, lib=None):
+    """Parse shard rows 'uuid,epoch,lat,lon,acc' into columns: (codes,
+    uuids, time, lat, lon, acc), where row i's uuid is ``uuids[codes[i]]``
+    and ``uuids`` holds each distinct uuid once, in order of first
+    appearance.  Torn rows are skipped.  Native when available,
+    numpy/python otherwise."""
     if lib is None:
         lib = get_lib()
     n_lines = data.count(b"\n") + 1
@@ -202,12 +205,10 @@ def parse_shard_bytes(data: bytes, lib=None):
         uoff = np.empty(n_lines, np.int64)
         ulen = np.empty(n_lines, np.int32)
         n = lib.rn_parse_shard(data, len(data), lat, lon, tm, acc, uoff, ulen, n_lines)
-        # "replace": a torn multi-byte character must not abort the batch
-        uuids = [
-            data[uoff[i] : uoff[i] + ulen[i]].decode(errors="replace") for i in range(n)
-        ]
-        return uuids, tm[:n].copy(), lat[:n].copy(), lon[:n].copy(), acc[:n].copy()
-    uuids, tms, lats, lons, accs = [], [], [], [], []
+        codes, uuids = _group_uuids(data, uoff[:n], ulen[:n])
+        return codes, uuids, tm[:n].copy(), lat[:n].copy(), lon[:n].copy(), acc[:n].copy()
+    ids: dict = {}
+    codes, tms, lats, lons, accs = [], [], [], [], []
     for line in data.decode(errors="replace").splitlines():
         # parse the whole row before appending anything, so a row that fails
         # on a late field can't leave the columns misaligned
@@ -218,18 +219,48 @@ def parse_shard_bytes(data: bytes, lib=None):
             row = (int(tm_), float(lat_), float(lon_), int(acc_))
         except ValueError:
             continue
-        uuids.append(uuid)
+        codes.append(ids.setdefault(uuid, len(ids)))
         tms.append(row[0])
         lats.append(row[1])
         lons.append(row[2])
         accs.append(row[3])
     return (
-        uuids,
+        np.asarray(codes, np.int64),
+        list(ids),
         np.asarray(tms, np.int64),
         np.asarray(lats, np.float64),
         np.asarray(lons, np.float64),
         np.asarray(accs, np.int32),
     )
+
+
+def _group_uuids(data: bytes, off: np.ndarray, ln: np.ndarray):
+    """(codes, uuids) for the uuid byte ranges ``data[off:off+ln]``: one
+    fixed-width key per row (its length, then its bytes zero-padded) and
+    one sort group the rows; each distinct uuid is decoded once."""
+    n = len(off)
+    if n == 0:
+        return np.zeros(0, np.int64), []
+    width = int(ln.max())
+    raw = np.frombuffer(data, np.uint8)
+    last = len(raw) - 1
+    key = np.zeros((n, 4 + width), np.uint8)
+    key[:, :4] = ln.astype("<i4").view(np.uint8).reshape(n, 4)
+    for j in range(width):
+        key[:, 4 + j] = np.where(j < ln, raw[np.minimum(off + j, last)], 0)
+    _, first, inv = np.unique(key.view("V%d" % (4 + width)).ravel(),
+                              return_index=True, return_inverse=True)
+    order = np.argsort(first)
+    rank = np.empty_like(order)
+    rank[order] = np.arange(len(order))
+    # "replace": a torn multi-byte character must not abort the batch; two
+    # byte strings that decode alike are one uuid, as a dict by name has it
+    ids: dict = {}
+    remap = np.array([
+        ids.setdefault(data[o : o + m].decode(errors="replace"), len(ids))
+        for o, m in zip(off[first[order]].tolist(), ln[first[order]].tolist())
+    ], np.int64)
+    return remap[rank[inv.ravel()]], list(ids)
 
 
 # -- CPython extension: wire-format record materialisation -------------------

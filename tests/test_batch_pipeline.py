@@ -9,7 +9,7 @@ import pytest
 from reporter_tpu.batch.pipeline import (
     LocalArchive,
     _cull_lines,
-    _windows,
+    _read_requests,
     compile_valuer,
     get_traces,
     make_matches,
@@ -33,12 +33,14 @@ def test_default_valuer():
     assert lat == "3.465725" and lon == "-76.5135033" and acc == "6.5"
 
 
-def test_windows_inactivity_split():
-    pts = [{"time": t} for t in (0, 10, 20, 200, 210, 500)]
-    wins = list(_windows(pts, 120))
+def test_windows_inactivity_split(tmp_path):
+    shard = tmp_path / "shard"
+    shard.write_text("".join("veh-1,%d,37.75,-122.45,5\n" % t
+                             for t in (0, 10, 20, 200, 210, 500)))
+    wins = _read_requests(str(shard), 120, "auto")
     # the lone trailing point is dropped (<2 points)
-    assert [len(w) for w in wins] == [3, 2]
-    assert wins[1][0]["time"] == 200
+    assert [len(w["trace"]) for w in wins] == [3, 2]
+    assert wins[1]["trace"][0]["time"] == 200
 
 
 def _row(sid, nid, t0=100):

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 import reporter_tpu.native as native
-from reporter_tpu.native import get_lib, parse_shard_bytes
+from reporter_tpu.native import get_lib, parse_shard_columns
 from reporter_tpu.tiles import codec
 from reporter_tpu.tiles.network import grid_city
 
@@ -112,45 +112,55 @@ def _python_parse(data):
     import unittest.mock as mock
 
     with mock.patch.object(native, "get_lib", lambda: None):
-        return native.parse_shard_bytes(data)
+        return native.parse_shard_columns(data)
+
+
+def _uuids(parsed):
+    """Row i's uuid, from the (codes, distinct uuids, ...) columns."""
+    return [parsed[1][c] for c in parsed[0]]
 
 
 def test_parse_shard_native_vs_python(lib):
-    na = parse_shard_bytes(SHARD, lib=lib)
+    na = parse_shard_columns(SHARD, lib=lib)
     py = _python_parse(SHARD)
-    assert na[0] == ["veh-1", "veh-2", "veh-1"]  # torn row skipped
-    assert list(na[1]) == [1483250740, 1483250750, 1483250760]
-    assert na[0] == py[0]
-    np.testing.assert_array_equal(na[1], py[1])
-    np.testing.assert_allclose(na[2], py[2])
+    assert _uuids(na) == ["veh-1", "veh-2", "veh-1"]  # torn row skipped
+    assert na[1] == ["veh-1", "veh-2"]  # each uuid once, first seen first
+    assert list(na[2]) == [1483250740, 1483250750, 1483250760]
+    np.testing.assert_array_equal(na[0], py[0])
+    assert na[1] == py[1]
+    np.testing.assert_array_equal(na[2], py[2])
     np.testing.assert_allclose(na[3], py[3])
-    np.testing.assert_array_equal(na[4], py[4])
+    np.testing.assert_allclose(na[4], py[4])
+    np.testing.assert_array_equal(na[5], py[5])
 
 
 def test_parse_shard_crlf(lib):
     """CRLF archives must parse identically on both paths."""
     crlf = SHARD.replace(b"\n", b"\r\n")
-    na = parse_shard_bytes(crlf, lib=lib)
+    na = parse_shard_columns(crlf, lib=lib)
     py = _python_parse(crlf)
-    assert na[0] == py[0] == ["veh-1", "veh-2", "veh-1"]
-    np.testing.assert_array_equal(na[1], py[1])
-    np.testing.assert_array_equal(na[4], py[4])
+    assert _uuids(na) == _uuids(py) == ["veh-1", "veh-2", "veh-1"]
+    np.testing.assert_array_equal(na[2], py[2])
+    np.testing.assert_array_equal(na[5], py[5])
 
 
 def test_parse_shard_edge_rows(lib):
     """Whitespace-only fields, leading-space uuids, and non-UTF-8 bytes must
-    behave the same on both paths."""
+    behave the same on both paths; distinct bytes that decode alike are one
+    uuid."""
     data = (
         b"veh-1,1483250740,37.75, ,5\n"      # whitespace lon: reject
         b"  veh-2,1483250750,37.76,-122.44,7\n"  # leading ws: uuid stripped
         b"veh-\xff3,1483250760,37.77,-122.43,4\n"  # invalid utf-8 in uuid
+        b"veh-\xfe3,1483250770,37.78,-122.42,4\n"  # decodes as the one above
     )
-    na = parse_shard_bytes(data, lib=lib)
+    na = parse_shard_columns(data, lib=lib)
     py = _python_parse(data)
-    assert na[0] == py[0]
-    assert na[0][0] == "veh-2"
-    assert len(na[0]) == 2  # bad-lon row dropped, other two kept
-    np.testing.assert_array_equal(na[1], py[1])
+    assert _uuids(na) == _uuids(py)
+    assert _uuids(na)[0] == "veh-2"
+    assert len(na[0]) == 3  # bad-lon row dropped, other three kept
+    assert list(na[0]) == list(py[0]) == [0, 1, 1]
+    np.testing.assert_array_equal(na[2], py[2])
 
 
 def test_shard_chunked_iter(tmp_path):
@@ -162,9 +172,9 @@ def test_shard_chunked_iter(tmp_path):
     # tiny chunks force the carry/split logic through every boundary
     rows = []
     total_lines = 0
-    for uuids, tms, lats, lons, accs, n_lines in _iter_shard_chunks(p, chunk_bytes=7):
-        rows.extend(zip(uuids, tms))
-        total_lines += n_lines
+    for parsed in _iter_shard_chunks(p, chunk_bytes=7):
+        rows.extend(zip(_uuids(parsed), parsed[2]))
+        total_lines += parsed[-1]
     assert [u for u, _ in rows] == ["veh-1", "veh-2", "veh-1"]
     assert total_lines == 4
 

@@ -425,3 +425,9 @@ def test_batch_head_metrics_flag(tmp_path, capsys):
             == _sample(before, "reporter_batch_points_gathered_total") + 8)
     # phase 2 ran in the head process; its counters ride the same snapshot
     assert "reporter_batch_windows_matched_total" in snap
+    # and every window it matched went to the matcher with its columns
+    matched = (_sample(snap, "reporter_batch_windows_matched_total")
+               - _sample(before, "reporter_batch_windows_matched_total"))
+    assert matched == 2
+    assert (_sample(snap, "reporter_batch_columnar_windows_total")
+            - _sample(before, "reporter_batch_columnar_windows_total")) == matched
