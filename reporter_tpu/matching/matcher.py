@@ -36,7 +36,8 @@ from . import columnar
 from .assoc_native import associate_segments_batch
 from .config import MatcherConfig
 from .sparse import (
-    C_SPARSE_DISPATCH, SparseModel, associate_interpolated, clamp_radius,
+    C_SPARSE_DISPATCH, C_SPARSE_POINTS, SparseModel, associate_interpolated,
+    clamp_radius,
 )
 
 log = logging.getLogger(__name__)
@@ -1182,6 +1183,8 @@ class SegmentMatcher:
                 slabel, pkey))
             if slabel:
                 C_SPARSE_DISPATCH.labels(slabel).inc(len(idxs))
+                C_SPARSE_POINTS.labels(slabel).inc(
+                    sum(len(traces[i]["trace"]) for i in idxs))
             chunks.extend(
                 (pkey, slabel, blen, idxs[i : i + cap])
                 for i in range(0, len(idxs), cap)
@@ -1223,6 +1226,8 @@ class SegmentMatcher:
         for (pkey, slabel), lidx in sorted(long_map.items()):
             if slabel:
                 C_SPARSE_DISPATCH.labels(slabel).inc(len(lidx))
+                C_SPARSE_POINTS.labels(slabel).inc(
+                    sum(len(traces[i]["trace"]) for i in lidx))
             long_handles.extend(self._dispatch_long(traces, lidx, pkey=pkey,
                                                     slabel=slabel, cols=cols))
 
@@ -2033,6 +2038,7 @@ class SegmentMatcher:
                     p, sp, _k_sp = self.sparse.params_for(slabel, pkey)
                     fn = self._get_jit("sparse_session", kernel)
                     C_SPARSE_DISPATCH.labels(slabel).inc(len(sub))
+                    C_SPARSE_POINTS.labels(slabel).inc(sum(ns))
                     t0 = _time.monotonic()
                     packed, aux, carry_out = fn(
                         self._dg, self._du, xin, p, sp, self.cfg.beam_k,
@@ -2185,6 +2191,8 @@ class SegmentMatcher:
                 p, sp, _k_sp = self.sparse.params_for(slabel, pkey)
                 fn = self._get_jit("sparse_arena_session", kernel)
                 C_SPARSE_DISPATCH.labels(slabel).inc(len(sub))
+                C_SPARSE_POINTS.labels(slabel).inc(
+                    sum(len(items[i]["points"]) for i in sub))
                 packed, aux, slab_out = fn(
                     self._dg, self._du, xin, p, sp, self.cfg.beam_k,
                     arena.hot, slots, use)
@@ -2223,6 +2231,7 @@ class SegmentMatcher:
             p, sp, _k_sp = self.sparse.params_for(slabel, item["pkey"])
             fn = self._get_jit("sparse_arena_session", kernel)
             C_SPARSE_DISPATCH.labels(slabel).inc()
+            C_SPARSE_POINTS.labels(slabel).inc(len(pts))
         else:
             p = self._params_for(item["pkey"])
             fn = self._get_jit("arena_session", kernel)
@@ -2293,6 +2302,7 @@ class SegmentMatcher:
             p, sp, _k_sp = self.sparse.params_for(slabel, item["pkey"])
             fn = self._get_jit("sparse_session", self._kernel_for(W))
             C_SPARSE_DISPATCH.labels(slabel).inc()
+            C_SPARSE_POINTS.labels(slabel).inc(len(pts))
         else:
             p = self._params_for(item["pkey"])
             fn = self._get_jit("session", self._kernel_for(W))

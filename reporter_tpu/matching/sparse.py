@@ -68,6 +68,12 @@ C_SPARSE_DISPATCH = obs.counter(
     "Traces dispatched through the sparse-gap program variants, by gap "
     "cohort (docs/match-quality.md \"Sparse gaps\")",
     ("cohort",))
+C_SPARSE_POINTS = obs.counter(
+    "reporter_sparse_points_total",
+    "GPS points of the traces dispatched through the sparse-gap program "
+    "variants, by gap cohort: the sparse path's share of the points "
+    "matched (beside reporter_sparse_dispatch_total's trace count)",
+    ("cohort",))
 G_CALIBRATED = obs.gauge(
     "reporter_sparse_calibrated",
     "1 when the sparse model is running per-cohort CALIBRATION.json "
