@@ -334,10 +334,18 @@ def run_device(platform: str, scenario, arrays, ubodt, cohorts) -> dict:
         # mirror SegmentMatcher._dispatch_batch's batch padding so the
         # kernel-only timing measures exactly the shapes/program e2e
         # dispatches even when env overrides pick off-rung cohort sizes.
-        # The forward speaks the packed transport ([4,B,T] in, [3,B,T] out).
+        # The forward speaks the packed transport ([4,B,T] in, [3,B,T] out;
+        # the dispatched program also returns its probe-path step counts,
+        # dropped here).
         px, py, tm, valid = SegmentMatcher._pad_batch(px, py, tm, valid)
         kernel = kernel or primary_kernel
-        fn = matcher._get_jit("compact", kernel)
+        jit = matcher._get_jit("compact", kernel)
+
+        def fn(*a):
+            return jit(*a)[0]
+
+        if hasattr(jit, "lower"):
+            fn.lower = jit.lower
         if cohort:
             forward_by_cohort[cohort] = kernel
         return fn, (dg, du, jnp.asarray(pack_inputs(px, py, tm, valid)), params)
@@ -411,7 +419,7 @@ def run_device(platform: str, scenario, arrays, ubodt, cohorts) -> dict:
         # the host sync cost.  Sizes come from xin_long, not the enclosing
         # px — later sections rebind px to other cohorts (the profiler
         # section used to crash on exactly that shadowing).
-        host_parts, outs, _aux = matcher._dispatch_long_group(
+        host_parts, outs, _aux, _probes = matcher._dispatch_long_group(
             xin_long, n_chunks, W, kernel=kernel or primary_kernel)
         if collect:
             # device-side concat -> one fetch (mirrors _fetch_long)

@@ -93,6 +93,15 @@ C_DISPATCH_COHORT = obs.counter(
     "and program kind (compact / pre / chain / carry / step; "
     "docs/performance.md)",
     ("cohort", "kind"))
+# UBODT probe engagement (ops/hashtable.ubodt_lookup_steps): every valid
+# step of a batched precompute, by the path that answered it; the program
+# returns the counts beside its result and they are counted at its fetch
+C_PROBE_STEPS = obs.counter(
+    "reporter_ubodt_probe_steps_total",
+    "Valid HMM steps of the batched programs by the UBODT probe path that "
+    "answered them: compact (the step's distinct node pairs), overflow "
+    "(the dispatch's K x K lane) or full (the whole dispatch at K x K)",
+    ("path",))
 C_WARM_SHAPES = obs.counter(
     "reporter_warmup_shapes_total",
     "Shapes pre-dispatched by warmup, by viterbi kernel",
@@ -107,6 +116,20 @@ C_POINTS = obs.counter(
 C_BREAKS = obs.counter(
     "reporter_transition_breaks_total",
     "Points flagged as HMM discontinuities (includes window starts)")
+
+
+def _count_probe_steps(counts) -> None:
+    """Add fetched [rows, 3] probe-path step counts (one per dispatch) to
+    C_PROBE_STEPS; pad rows hold no valid step."""
+    from ..ops.hashtable import PROBE_PATHS
+
+    tot = np.zeros(3, np.int64)
+    for c in counts:
+        tot += np.asarray(c).sum(axis=0)
+    for path, n in zip(PROBE_PATHS, tot.tolist()):
+        if n:
+            C_PROBE_STEPS.labels(path).inc(n)
+
 
 # chunks allowed in flight on the device while the host associates earlier
 # ones.  Each in-flight chunk pins its packed input + result,
@@ -786,15 +809,15 @@ class SegmentMatcher:
         "sparse_arena_session": ("dg", "du", "xin", "p", "sp", "k",
                                  "slab", "slots", "use"),
     }
-    # result names per kind (qa variants append/insert "aux"); resolved
-    # against the same rule table for out_specs
+    # result names per kind (qa variants insert "aux" after "packed");
+    # resolved against the same rule table for out_specs
     _PROGRAM_OUTS = {
-        "compact": ("packed",),
+        "compact": ("packed", "probe"),
         "carry": ("packed", "carry"),
         "pre": ("pre",),
         "chain": ("packed", "carry"),
         "session": ("packed", "aux", "carry"),
-        "sparse": ("packed", "aux"),
+        "sparse": ("packed", "aux", "probe"),
         "sparse_pre": ("pre",),
         "sparse_chain": ("packed", "aux", "carry"),
         "sparse_session": ("packed", "aux", "carry"),
@@ -997,7 +1020,7 @@ class SegmentMatcher:
                 p, sp, k_sp = self.sparse.params_for(slabel, pkey)
                 fn = self._get_jit("sparse", kernel)
                 t0 = _time.monotonic()
-                res, aux = fn(self._dg, self._du, xin, p, sp, k_sp)
+                res, aux, probe = fn(self._dg, self._du, xin, p, sp, k_sp)
                 C_DISPATCHES.labels(kernel).inc()
                 C_DISPATCH_COHORT.labels("bucketed", "sparse").inc()
                 self._note_dispatch(
@@ -1007,21 +1030,25 @@ class SegmentMatcher:
                 if not qa:
                     aux = None
                 self._start_host_copy(res)
-                return ("jax", B, res, aux)
+                self._start_host_copy(probe)
+                return ("jax", B, res, aux, probe)
             p = self._params_for(pkey)
             fn = self._get_jit("compact", kernel)
             t0 = _time.monotonic()
             res = fn(self._dg, self._du, xin, p, self.cfg.beam_k)
             aux = None
             if qa:
-                res, aux = res
+                res, aux, probe = res
+            else:
+                res, probe = res
             C_DISPATCHES.labels(kernel).inc()
             C_DISPATCH_COHORT.labels("bucketed", "compact").inc()
             self._note_dispatch(
                 px.shape, _time.monotonic() - t0, kernel=kernel, fn=fn,
                 args=(self._dg, self._du, xin, p, self.cfg.beam_k))
             self._start_host_copy(res)
-            return ("jax", B, res, aux)
+            self._start_host_copy(probe)
+            return ("jax", B, res, aux, probe)
         cpu = self._cpu if not pkey else self._cpu_for(pkey)
         return ("cpu", cpu.run_batch(px, py, times, valid))
 
@@ -1096,11 +1123,12 @@ class SegmentMatcher:
         if handle[0] == "jax":
             from ..ops.viterbi import unpack_compact
 
-            _, B, res, aux = handle
+            _, B, res, aux, probe = handle
             with obs_attrib.host_stage("fetch", n=B):
                 edge, offset, breaks = unpack_compact(res)
                 if aux is not None:
                     aux = np.asarray(aux)[:B]
+                _count_probe_steps([probe])
             return (edge[:B], offset[:B], breaks[:B]), aux
         return handle[1], None
 
@@ -1613,10 +1641,10 @@ class SegmentMatcher:
             # behaviour had this bound implicitly; fully-async dispatch of
             # many groups would pin every group's inputs + tail at once)
             if len(handles) >= 2:
-                grp, parts, tail, tms, gaux = handles[-2]
+                grp, parts, tail, tms, gaux, gprobe = handles[-2]
                 if tail:
                     parts.extend(unpack_compact(o) for o in tail)
-                    handles[-2] = (grp, parts, [], tms, gaux)
+                    handles[-2] = (grp, parts, [], tms, gaux, gprobe)
             group = order[g : g + cap]
             T_max = max(len(traces[i]["trace"]) for i in group)
             n_chunks = -(-T_max // W)
@@ -1630,15 +1658,15 @@ class SegmentMatcher:
                     px, py, tm, valid
                 )
             xin = pack_inputs(px, py, tm, valid)  # [4, B_pad, n_chunks*W]
-            host_parts, outs, aux_dev = self._dispatch_long_group(
+            host_parts, outs, aux_dev, probes = self._dispatch_long_group(
                 xin, n_chunks, W, params=self._params_for(pkey),
                 pkey=pkey, slabel=slabel)
             # chunk outputs come back one host copy each and join on the
             # host: a device-side concatenate would compile once per
             # chunk count, i.e. on the request path for every new length
-            for o in outs:
+            for o in outs + probes:
                 self._start_host_copy(o)
-            handles.append((group, host_parts, outs, times, aux_dev))
+            handles.append((group, host_parts, outs, times, aux_dev, probes))
         return handles
 
     def _dispatch_long_group(self, xin, n_chunks: int, W: int,
@@ -1646,11 +1674,13 @@ class SegmentMatcher:
                              pkey: tuple = (), slabel: str = ""):
         """Dispatch every device program for ONE padded long-trace group.
         xin: packed [4, B_pad, n_chunks*W] numpy.  Returns (host_parts,
-        outs, aux): already-fetched (edge, offset, breaks) wave tuples, the
-        still-on-device packed chunk outputs in chunk order, and the
-        group's on-device [B_pad, 4] confidence block (seam-combined
+        outs, aux, probes): already-fetched (edge, offset, breaks) wave
+        tuples, the still-on-device packed chunk outputs in chunk order,
+        the group's on-device [B_pad, 4] confidence block (seam-combined
         across chunks as the chain advances; None with quality
-        diagnostics off or on the legacy fused path).  Everything
+        diagnostics off or on the legacy fused path), and each pre
+        dispatch's on-device [rows, 3] probe-path step counts (none on the
+        legacy fused path).  Everything
         enqueues asynchronously; bench.py times exactly this entry point so
         the measured programs are the dispatched ones.
 
@@ -1693,7 +1723,7 @@ class SegmentMatcher:
         if self._carry_sharding is not None:
             carry = jax.device_put(carry, self._carry_sharding)
 
-        outs, host_parts = [], []
+        outs, host_parts, probes = [], [], []
         # confidence aux rides the hoisted chain programs only (the legacy
         # fused carry is the bit-exact differential reference and stays
         # untouched); components combine across seams as min / + / + / +
@@ -1732,7 +1762,7 @@ class SegmentMatcher:
                     args=(self._dg, self._du,
                           xin[:, :, :W], p, k, carry))
                 _bank(out)
-            return host_parts, outs, None
+            return host_parts, outs, None, []
 
         fn_pre = self._get_jit("sparse_pre" if slabel else "pre", "none")
         fn_chain = self._get_jit("sparse_chain" if slabel else "chain",
@@ -1767,6 +1797,7 @@ class SegmentMatcher:
                 pre = fn_pre(self._dg, self._du, self._put_packed(seg),
                              p, k)
                 pre_args = (self._dg, self._du, seg, p, k)
+            probes.append(pre.probe)
             C_DISPATCH_COHORT.labels("long", "pre").inc()
             self._note_dispatch((rung, W), _time.monotonic() - t0,
                                 kind="sparse_pre" if slabel else "pre",
@@ -1805,7 +1836,7 @@ class SegmentMatcher:
                                     kernel=kernel, fn=fn_chain,
                                     args=chain_args)
                 _bank(out)
-        return host_parts, outs, aux_acc
+        return host_parts, outs, aux_acc, probes
 
     def _fetch_long(self, handle):
         """Block on one _dispatch_long group handle -> (group, (edge,
@@ -1817,8 +1848,9 @@ class SegmentMatcher:
         ([B, 4] numpy or None), trimmed of batch-pad rows."""
         from ..ops.viterbi import unpack_compact
 
-        group, host_parts, dev_tail, times, aux_dev = handle
+        group, host_parts, dev_tail, times, aux_dev, probes = handle
         with obs_attrib.host_stage("fetch", n=len(group)):
+            _count_probe_steps(probes)
             parts = list(host_parts)
             parts.extend(unpack_compact(o) for o in dev_tail)
             if len(parts) == 1:

@@ -70,6 +70,7 @@ STAGES = (
     "transition-build",  # edge-row gathers + [K, K] transition arithmetic
     "ubodt-probe",       # bucket-row gathers (1 wide32 / 2 cuckoo per pair)
     "select",            # in-row key match + field reduce
+    "probe-compact",     # per-step distinct-node compaction, lane, expansion
     "dedup-sort",        # in-batch probe dedup: lexicographic pair sort
     "dedup-compact",     # segment-head compaction scatter
     "dedup-scatter",     # result scatter-back through segment ids

@@ -35,10 +35,25 @@ the executed row count changes.  Dedup only applies at the top level of a
 jitted program (it sorts across the whole key set); under the gp-sharded
 probe it is skipped (the bucket-range masking already drops remote rows).
 
+**Per-step compaction** (``ubodt_lookup_steps``): a batched transition
+build probes every step's K x K (src, dst) pairs, but candidate edges
+around one junction share end nodes, so a step's K sources and K
+destinations hold only a few distinct nodes each (measured on the metro
+city: at most 6 of 8 per side in 99.97 % of 15 s and 1 Hz steps, at most
+8 of 16 in 99.8 % of 60 s steps; PERF.md section 5).  Each step's distinct
+nodes are found with dense [K, K] compares, only their C x C pairs are
+probed, and the K x K answers are rebuilt by exact selects.  Steps with
+more than C distinct nodes a side take a static overflow lane probed at
+full K x K; a dispatch with more of them than the lane holds probes
+everything at K x K.  Results are bit-identical in every case; only the
+rows gathered change.
+
 Must mirror tiles/ubodt.py's host-side layouts and hashes exactly.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -246,6 +261,136 @@ def ubodt_lookup(u: DeviceUBODT, src: jnp.ndarray, dst: jnp.ndarray,
     if dedup and src.size >= _DEDUP_MIN_PAIRS:
         return _lookup_dedup(u, src, dst)
     return _lookup_plain(u, src, dst)
+
+
+# step counters of ubodt_lookup_steps, in the order of its counts' last axis
+PROBE_PATHS = ("compact", "overflow", "full")
+# overflow lane slots per dispatch = steps // ratio (at least _LANE_MIN):
+# the worst file of the backfill traffic overflows 0.23 % of its steps at
+# K=16 and 0.04 % at K=8 (PERF.md section 5), so 1/64 leaves ~7x room
+_LANE_RATIO = 64
+_LANE_MIN = 8
+
+
+def compact_width(k: int) -> int:
+    """Distinct nodes a side that the compacted probe gathers for a beam of
+    ``k``: the least C with C^2 >= 4k (6 for K=8, 8 for K=16), from the
+    measured distinct-node distribution (PERF.md section 5).  C >= k means
+    no compaction."""
+    return min(k, math.isqrt(4 * k - 1) + 1)
+
+
+def lane_width(n_steps: int) -> int:
+    """Overflow-lane step slots for a dispatch of ``n_steps`` steps: 1/64
+    of them, at least 8, rounded up to a multiple of 8."""
+    r = max(_LANE_MIN, -(-n_steps // _LANE_RATIO))
+    return -(-r // 8) * 8
+
+
+def _distinct(x: jnp.ndarray, c: int):
+    """x: [..., K] node ids -> (slot [..., K] i32, nodes [..., c] i32, n
+    [...] i32): the n distinct values of x in first-occurrence order, padded
+    to c by repeating x[..., 0], and each slot's index among them (clamped
+    to c - 1 where n > c).  Dense [K, K] compares and masked sums: no
+    gather, sort or scatter."""
+    k = x.shape[-1]
+    i = jnp.arange(k)
+    eq = x[..., :, None] == x[..., None, :]  # [..., K, K]
+    first = ~jnp.any(eq & (i[None, :] < i[:, None]), axis=-1)  # [..., K]
+    rank = jnp.sum(first[..., None, :] & (i[None, :] <= i[:, None]),
+                   axis=-1, dtype=jnp.int32) - 1
+    n = jnp.sum(first, axis=-1, dtype=jnp.int32)
+    slot = jnp.sum(jnp.where(eq & first[..., None, :], rank[..., None, :], 0),
+                   axis=-1, dtype=jnp.int32)
+    cc = jnp.arange(c)
+    pick = first[..., None, :] & (rank[..., None, :] == cc[:, None])  # [.., c, K]
+    nodes = jnp.sum(jnp.where(pick, x[..., None, :], 0), axis=-1,
+                    dtype=jnp.int32)
+    nodes = jnp.where(cc < n[..., None], nodes, x[..., :1])
+    return jnp.minimum(slot, c - 1), nodes, n
+
+
+def _expand(v: jnp.ndarray, sa: jnp.ndarray, sb: jnp.ndarray) -> jnp.ndarray:
+    """v: [..., c, c] compact answers -> [..., K, K] with out[i, j] =
+    v[sa[i], sb[j]], by one-hot select-min (exact, +inf included)."""
+    cc = jnp.arange(v.shape[-1])
+    rows = jnp.min(jnp.where((sa[..., :, None] == cc)[..., :, :, None],
+                             v[..., None, :, :], jnp.inf), axis=-2)  # [.., K, c]
+    return jnp.min(jnp.where((sb[..., :, None] == cc)[..., None, :, :],
+                             rows[..., :, None, :], jnp.inf), axis=-1)
+
+
+def ubodt_lookup_steps(u: DeviceUBODT, src: jnp.ndarray, dst: jnp.ndarray,
+                       step_valid: jnp.ndarray, dedup: bool = False):
+    """The K x K probe of every step of a batch through per-step
+    compaction (module docstring).  src/dst: [B, S, K] i32 node ids of
+    each step's source / destination candidates; step_valid: [B, S] bool.
+
+    Returns (dist, time, counts): dist/time [B, S, K, K] bit-identical to
+    ``ubodt_lookup(u, src[..., :, None], dst[..., None, :])`` at every
+    valid step (an invalid step never takes the lane, and its answers are
+    never read), and counts [B, 3] i32: each row's valid steps by the path
+    that answered them (PROBE_PATHS).  ``dedup`` passes through to the
+    compacted and the whole-dispatch probes."""
+    nb, ns, k = src.shape
+    n = nb * ns
+    c = compact_width(k)
+    r = lane_width(n)
+    n_valid = jnp.sum(step_valid, axis=1, dtype=jnp.int32)  # [B]
+    zero = jnp.zeros_like(n_valid)
+    if c >= k or r >= n:  # nothing to save: every step probes K x K
+        dist, time, _ = ubodt_lookup(u, src[..., :, None], dst[..., None, :],
+                                     dedup=dedup)
+        return dist, time, jnp.stack([zero, zero, n_valid], axis=1)
+
+    with stage("probe-compact"):
+        sa, la, na = _distinct(src, c)
+        sb, lb, nd = _distinct(dst, c)
+        over = ((na > c) | (nd > c)) & step_valid  # [B, S]
+        flat = over.reshape(-1)
+        pos = jnp.cumsum(flat.astype(jnp.int32)) - 1
+        n_over = pos[-1] + 1
+        # lane slot -> step; unused slots hold n + slot, out of range: they
+        # probe step n-1 and their write-back is dropped
+        lane = (n + jax.lax.iota(jnp.int32, r)).at[
+            jnp.where(flat, pos, r)].set(jax.lax.iota(jnp.int32, n),
+                                         mode="drop")
+        step = jnp.minimum(lane, n - 1)
+        lsrc = src.reshape(n, k)[step]  # [R, K]
+        ldst = dst.reshape(n, k)[step]
+
+    def _compacted(_):
+        dc, tc, _ = ubodt_lookup(u, la[..., :, None], lb[..., None, :],
+                                 dedup=dedup)  # [B, S, C, C]
+        dl, tl, _ = ubodt_lookup(u, lsrc[:, :, None], ldst[:, None, :])
+        with stage("probe-compact"):
+            dist = _expand(dc, sa, sb).reshape(n, k, k)
+            time = _expand(tc, sa, sb).reshape(n, k, k)
+            dist = dist.at[lane].set(dl, mode="drop", unique_indices=True)
+            time = time.at[lane].set(tl, mode="drop", unique_indices=True)
+            return dist.reshape(nb, ns, k, k), time.reshape(nb, ns, k, k)
+
+    def _whole(_):
+        # K x K for every step, a slice of rows at a time, so that this
+        # branch holds no more gathered rows than the compacted one
+        m = next(d for d in range(-(-k * k // (c * c)), 0, -1) if nb % d == 0)
+
+        def _rows(sd):
+            dist, time, _ = ubodt_lookup(u, sd[0][..., :, None],
+                                         sd[1][..., None, :], dedup=dedup)
+            return dist, time
+
+        dist, time = jax.lax.map(_rows, (src.reshape(m, nb // m, ns, k),
+                                         dst.reshape(m, nb // m, ns, k)))
+        return dist.reshape(nb, ns, k, k), time.reshape(nb, ns, k, k)
+
+    fallback = n_over > r
+    dist, time = jax.lax.cond(fallback, _whole, _compacted, None)
+    n_lane = jnp.sum(over, axis=1, dtype=jnp.int32)
+    counts = jnp.where(
+        fallback, jnp.stack([zero, zero, n_valid], axis=1),
+        jnp.stack([n_valid - n_lane, n_lane, zero], axis=1))
+    return dist, time, counts
 
 
 def _ubodt_lookup_sharded(u: DeviceUBODT, src: jnp.ndarray, dst: jnp.ndarray):

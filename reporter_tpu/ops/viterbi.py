@@ -52,7 +52,7 @@ from ..obs.attrib import stage
 from ..tiles.arrays import DeviceGraph
 from ..tiles.ubodt import DeviceUBODT
 from .candidates import Candidates, find_candidates_batch
-from .hashtable import ubodt_lookup
+from .hashtable import ubodt_lookup, ubodt_lookup_steps
 
 NEG_INF = -1e30
 
@@ -171,6 +171,9 @@ class MatchResult(NamedTuple):
     # across chunk seams (min / + / + / +), so the long-trace path can sum
     # them per chunk.  Purely diagnostic: never feeds back into the match.
     aux: jnp.ndarray
+    # [3] i32 steps of this row by the UBODT probe path that answered them
+    # (hashtable.PROBE_PATHS); set by the batched programs, None per trace
+    probe: "jnp.ndarray | None" = None
 
 
 def transition_matrix(dg: DeviceGraph, du: DeviceUBODT, src: Candidates, dst: Candidates,
@@ -321,6 +324,9 @@ class TracePre(NamedTuple):
     logp: jnp.ndarray  # [T-1, K, K] transition log-probs per step
     route: jnp.ndarray  # [T-1, K, K] route distances per step
     gc: jnp.ndarray  # [T-1] great-circle metres between consecutive points
+    # [3] i32 valid steps by the UBODT probe path that answered them
+    # (hashtable.PROBE_PATHS): precompute_batch only, None per trace
+    probe: "jnp.ndarray | None" = None
 
 
 def precompute_trace(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid,
@@ -370,10 +376,14 @@ def precompute_batch(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid,
         ([B, T, K] rows) and sliced into the src/dst views, instead of
         twice per step (as transition src, again as transition dst);
       * the UBODT route-distance probe runs as ONE call over the batch's
-        entire [B, T-1, K, K] key set — the only level where in-batch
+        entire [B, T-1, K, K] key set, compacted per step to each step's
+        distinct node pairs (ops/hashtable.ubodt_lookup_steps); in-batch
         probe dedup (``dedup=True`` -> ops/hashtable._lookup_dedup's
-        sort-unique-gather-scatter) can deduplicate across the whole
-        dispatch rather than per step or per trace.
+        sort-unique-gather-scatter) applies to the compacted key set of
+        the whole dispatch.
+
+    ``TracePre.probe`` ([B, 3] i32) counts each row's valid steps by the
+    probe path that answered them.
 
     The per-step transition arithmetic then runs with ``pre`` supplied, so
     XLA sees the same elementwise ops as the fused per-trace program.
@@ -395,9 +405,8 @@ def precompute_batch(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid,
         era, erb = er[:, :-1], er[:, 1:]  # [B, T-1, K, 8]
         to_a = jax.lax.bitcast_convert_type(era[..., 0], jnp.int32)
         from_b = jax.lax.bitcast_convert_type(erb[..., 1], jnp.int32)
-    sp_dist, sp_time, _ = ubodt_lookup(
-        du, to_a[..., :, None], from_b[..., None, :], dedup=dedup
-    )  # [B, T-1, K, K]
+    sp_dist, sp_time, probe = ubodt_lookup_steps(
+        du, to_a, from_b, valid[:, 1:], dedup=dedup)  # [B, T-1, K, K]
 
     src_c = jax.tree_util.tree_map(lambda a: a[:, :-1], cand)
     dst_c = jax.tree_util.tree_map(lambda a: a[:, 1:], cand)
@@ -414,7 +423,8 @@ def precompute_batch(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid,
         logp_all, route_all = tm(
             dg, du, src_c, dst_c, gc, dts, p, (era, erb, sp_dist, sp_time),
             sp)
-    return TracePre(cand=cand, emis=emis, logp=logp_all, route=route_all, gc=gc)
+    return TracePre(cand=cand, emis=emis, logp=logp_all, route=route_all,
+                    gc=gc, probe=probe)
 
 
 def match_trace(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid, p: MatchParams, k: int,
@@ -454,7 +464,7 @@ def chain_trace(dg: DeviceGraph, du: DeviceUBODT, pre: TracePre, px, py, times,
     a TracePre; semantics identical to the fused match_trace by
     construction (it IS the tail of that function)."""
     T = px.shape[0]
-    cand, emis, logp_all, route_all, gc = pre
+    cand, emis, logp_all, route_all, gc = pre[:5]
     # gap-conditioned breakage (sparse model): each step's teleport
     # threshold scales with its measurement gap.  With sp None the
     # per-step threshold is the traced scalar and the step function below
@@ -784,9 +794,10 @@ def match_batch(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid, p: Match
 
     pre = precompute_batch(dg, du, px, py, times, valid, p, k, dedup, sp)
     fn = functools.partial(chain_trace, kernel=kernel, sp=sp)
-    return jax.vmap(fn, in_axes=(None, None, 0, 0, 0, 0, 0, None, None))(
+    res = jax.vmap(fn, in_axes=(None, None, 0, 0, 0, 0, 0, None, None))(
         dg, du, pre, px, py, times, valid, p, k
     )
+    return res._replace(probe=pre.probe)
 
 
 class CompactMatch(NamedTuple):
@@ -801,6 +812,7 @@ class CompactMatch(NamedTuple):
     offset: jnp.ndarray  # [B, T] f32 metres along edge
     breaks: jnp.ndarray  # [B, T] bool
     aux: "jnp.ndarray | None" = None  # [B, 4] f32 confidence diagnostics
+    probe: "jnp.ndarray | None" = None  # [B, 3] i32 steps by probe path
 
 
 def match_batch_compact(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid, p: MatchParams, k: int,
@@ -818,7 +830,7 @@ def _compact(res: MatchResult) -> CompactMatch:
         offset = jnp.take_along_axis(res.cand.offset, sel, axis=-1)[..., 0]
         edge = jnp.where(res.idx >= 0, edge, -1)
         return CompactMatch(edge=edge, offset=offset, breaks=res.breaks,
-                            aux=res.aux)
+                            aux=res.aux, probe=res.probe)
 
 
 def match_batch_carry(dg: DeviceGraph, du: DeviceUBODT, px, py, times, valid,
@@ -883,11 +895,14 @@ def unpack_compact(out):
 def match_batch_compact_packed(dg: DeviceGraph, du: DeviceUBODT, xin,
                                p: MatchParams, k: int,
                                kernel: str = "scan",
-                               dedup: bool = False) -> jnp.ndarray:
-    """match_batch_compact over a packed [4, B, T] input -> packed [3, B, T]."""
+                               dedup: bool = False):
+    """match_batch_compact over a packed [4, B, T] input -> (packed
+    [3, B, T], probe [B, 3]): the result and each row's valid steps by the
+    UBODT probe path that answered them (TracePre.probe)."""
     px, py, times, valid = unpack_inputs(xin)
-    return pack_compact(match_batch_compact(
-        dg, du, px, py, times, valid, p, k, kernel, dedup))
+    cm = match_batch_compact(dg, du, px, py, times, valid, p, k, kernel,
+                             dedup)
+    return pack_compact(cm), cm.probe
 
 
 def match_batch_compact_packed_aux(dg: DeviceGraph, du: DeviceUBODT, xin,
@@ -895,14 +910,15 @@ def match_batch_compact_packed_aux(dg: DeviceGraph, du: DeviceUBODT, xin,
                                    kernel: str = "scan",
                                    dedup: bool = False):
     """match_batch_compact_packed + the per-trace confidence block:
-    (packed [3, B, T], aux [B, 4]).  Same match program (the packed wire
-    layout is untouched); the aux output merely keeps the confidence ops
-    live through XLA's DCE.  The serving matcher dispatches this variant
-    when quality diagnostics are enabled (docs/match-quality.md)."""
+    (packed [3, B, T], aux [B, 4], probe [B, 3]).  Same match program (the
+    packed wire layout is untouched); the aux output merely keeps the
+    confidence ops live through XLA's DCE.  The serving matcher dispatches
+    this variant when quality diagnostics are enabled
+    (docs/match-quality.md)."""
     px, py, times, valid = unpack_inputs(xin)
     cm = match_batch_compact(dg, du, px, py, times, valid, p, k, kernel,
                              dedup)
-    return pack_compact(cm), cm.aux
+    return pack_compact(cm), cm.aux, cm.probe
 
 
 def match_batch_carry_packed(dg: DeviceGraph, du: DeviceUBODT, xin,
@@ -1159,12 +1175,12 @@ def match_batch_compact_packed_sparse(dg: DeviceGraph, du: DeviceUBODT, xin,
                                       k: int, kernel: str = "scan",
                                       dedup: bool = False):
     """The sparse-cohort twin of match_batch_compact_packed_aux: packed
-    [4, B, T] in -> (packed [3, B, T], aux [B, 4]), with the time-adaptive
-    transition model and gap-conditioned breakage applied."""
+    [4, B, T] in -> (packed [3, B, T], aux [B, 4], probe [B, 3]), with the
+    time-adaptive transition model and gap-conditioned breakage applied."""
     px, py, times, valid = unpack_inputs(xin)
     cm = match_batch_compact(dg, du, px, py, times, valid, p, k, kernel,
                              dedup, sp)
-    return pack_compact(cm), cm.aux
+    return pack_compact(cm), cm.aux, cm.probe
 
 
 def precompute_batch_packed_sparse(dg: DeviceGraph, du: DeviceUBODT, xin,

@@ -35,8 +35,9 @@ What the table says (and why):
                  "dp" — the candidate quadrant sweep, emissions, and the
                  K-state recursion all ride the batch axis; the [K]
                  recursion itself stays local per docs/pallas-decision.md.
-  pre / carry /  leading-[B] pytrees and the [B, 4] confidence block:
-  aux            row-sharded over "dp" alongside the batch.
+  pre / carry /  leading-[B] pytrees, the [B, 4] confidence block and
+  aux / probe    the [B, 3] probe-path step counts: row-sharded over "dp"
+                 alongside the batch.
   slab           the session arena's [S]-slot beam slab: slot-sharded
                  over "dp" — a replica's carried beams live in POD-level
                  HBM, not one chip's.
@@ -69,7 +70,7 @@ PROGRAM_RULES: Tuple[Tuple[str, P], ...] = (
     (r"^(dg|p|sp)(/|$)", P()),
     (r"^du(/|$)", P(GRAPH_AXIS)),
     (r"^(xin|packed)(/|$)", P(None, BATCH_AXIS)),
-    (r"^(pre|carry|aux)(/|$)", P(BATCH_AXIS)),
+    (r"^(pre|carry|aux|probe)(/|$)", P(BATCH_AXIS)),
     (r"^slab(/|$)", P(BATCH_AXIS)),
     (r"^(slots|use)(/|$)", P()),
 )

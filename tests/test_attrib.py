@@ -324,7 +324,7 @@ class TestDifferential:
                     vt.match_batch_compact_packed, kernel=kernel, dedup=True),
                     static_argnums=(4,))
                 outs[flag] = np.asarray(
-                    fn(m._dg, m._du, xin, m._params, m.cfg.beam_k))
+                    fn(m._dg, m._du, xin, m._params, m.cfg.beam_k)[0])
             assert np.array_equal(outs["1"], outs["0"]), kernel
 
 

@@ -256,8 +256,8 @@ def test_gap_conditioned_breakage_connects():
     valid = np.ones((1, 2), bool)
     xin = V.pack_inputs(px, py, tm, valid)
     out_d = V.unpack_compact(V.match_batch_compact_packed(
-        dg, du, xin, p, cfg.beam_k))
-    out_s, _aux = V.match_batch_compact_packed_sparse(
+        dg, du, xin, p, cfg.beam_k)[0])
+    out_s, _aux, _probe = V.match_batch_compact_packed_sparse(
         dg, du, xin, p, sp, cfg.beam_k)
     out_s = V.unpack_compact(out_s)
     assert bool(out_d[2][0, 1]) is True  # dense: the hop restarts the HMM

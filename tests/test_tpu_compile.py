@@ -202,3 +202,75 @@ def test_dp2_gp2_sharded_probe(topo, tiered):
                         _like(m._params, sharding_for("p", mesh)))
     text = compiled.as_text()
     assert "all-reduce" in text or "all-gather" in text
+
+
+def _table_rows(text: str):
+    """{branch: [rows, ...]} of every row gather out of the UBODT in a
+    compiled module, read by the benchmark's roofline reader
+    (``benchmark/metrics/ubodt_probe_roofline.row_bytes``): its operand
+    shapes are written back into each instruction as the profiler's long
+    names carry them.  ``branch`` is the lax.cond branch the gather sits
+    in (0 = compacted, 1 = whole dispatch at K x K), -1 outside one."""
+    import importlib.util
+    import os
+    import re
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                        "metrics", "ubodt_probe_roofline.py")
+    spec = importlib.util.spec_from_file_location("roofline_reader", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+
+    define = re.compile(r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+) ")
+    shapes = {}
+    for line in text.splitlines():
+        m = define.match(line)
+        if m:
+            shapes[m.group(1)] = m.group(2)
+    out = {}
+    for line in text.splitlines():
+        m = define.match(line)
+        if not m or "rs.ubodt-probe" not in line:
+            continue
+        call = re.search(r" (gather|fusion)\(([^)]*)\)", line)
+        if not call:
+            continue
+        ops = ", ".join("%s %s" % (shapes.get(o.strip(), "?"), o.strip())
+                        for o in call.group(2).split(","))
+        long_name = "%s = %s %s(%s)" % (m.group(1), m.group(2),
+                                        call.group(1), ops)
+        nbytes = reader.row_bytes(long_name)
+        if nbytes:
+            br = re.search(r"cond/branch_(\d)_fun", line)
+            out.setdefault(int(br.group(1)) if br else -1, []).append(
+                nbytes // 512)
+    return out
+
+
+def test_sparse_probe_gathers(matcher, one_chip):
+    """The K=16 sparse program's probe, compiled for the v5e: the taken
+    branch gathers steps x C^2 + lane x K^2 rows twice (cuckoo), each as a
+    2-D [rows, 128] gather out of the [buckets, 128] table, which is what
+    the benchmark's ubodt_probe_roofline counts; the whole-dispatch branch
+    gathers the K x K rows a slice of the batch at a time."""
+    import jax.numpy as jnp
+
+    from reporter_tpu.obs.quality import GAP_BUCKETS
+    from reporter_tpu.ops.hashtable import compact_width, lane_width
+
+    B, T = 8, 32
+    dg, _du = _tables(one_chip, one_chip)
+    from reporter_tpu.tiles.ubodt import DeviceUBODT
+
+    du = DeviceUBODT(_sds((4096, 128), jnp.int32, one_chip), 4095)
+    p, sp, k = matcher.sparse.params_for(GAP_BUCKETS[-1][1], ())
+    assert k == 16
+    compiled = _compile(matcher._get_jit("sparse", "scan"), dg, du,
+                        _sds((4, B, T), jnp.float32, one_chip),
+                        _like(p, one_chip), _like(sp, one_chip), k)
+    rows = _table_rows(compiled.as_text())
+    steps, c, r = B * (T - 1), compact_width(k), lane_width(B * (T - 1))
+    assert sorted(rows[0]) == sorted([steps * c * c] * 2 + [r * k * k] * 2)
+    m = -(-k * k // (c * c))
+    assert sorted(rows[1]) == [steps // m * k * k] * 2
+    assert -1 not in rows

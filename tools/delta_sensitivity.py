@@ -91,7 +91,7 @@ def main() -> int:
             px, py, tm, valid = cohort_xy(arrays, straces, T)
             xin = jnp.asarray(pack_inputs(px, py, tm, valid))
             edge, _offset, breaks = unpack_compact(
-                jit_match(dg, du, xin, p, cfg.beam_k))
+                jit_match(dg, du, xin, p, cfg.beam_k)[0])
             agr = float(np.mean([
                 segment_agreement(arrays, edge[i], straces[i])
                 for i in range(len(straces))
